@@ -11,7 +11,9 @@ either way).  This section times both at engine geometry across the
     kern_tick_fused_j{J}      fused tick-step, us/tick
     kern_tick_speedup_j{J}    ref/fused ratio — the gated perf row
     kern_tick_budget_us_j{J}  roofline-derived per-tick budget (ungated;
-                              repro.roofline.analysis.tick_step_roofline)
+                              repro.roofline.analysis.tick_step_roofline),
+                              emitted on a TPU only: peaks are keyed by the
+                              device kind, and a CPU has no entry
 
 ``BENCH_KERN_ITERS`` shrinks the timing loop for CI smoke.
 """
@@ -79,11 +81,11 @@ def run_kern() -> list[tuple]:
     iters = int(os.environ.get("BENCH_KERN_ITERS", "30"))
     rows = []
     fused = jax.jit(functools.partial(tick_step, mode="themis", impl="auto"))
+    on_tpu = jax.default_backend() == "tpu"
     for j in LADDER:
         args = _inputs(j)
         ref_us = _time(_scan_phase, *args, iters=iters, warmup=2)
         fused_us = _time(fused, *args, iters=iters, warmup=2)
-        roof = tick_step_roofline(N_SERVERS, j, N_WORKERS)
         speedup = ref_us / fused_us if fused_us else 0.0
         rows.append((f"kern_tick_ref_j{j}", f"{ref_us:.1f}",
                      f"{ref_us:.1f} us/tick ({N_WORKERS}-step scan, "
@@ -92,6 +94,10 @@ def run_kern() -> list[tuple]:
                      f"{fused_us:.1f} us/tick (fused tick-step, auto impl)"))
         rows.append((f"kern_tick_speedup_j{j}", "",
                      f"{speedup:.2f}x ref/fused"))
+        if not on_tpu:
+            continue
+        roof = tick_step_roofline(N_SERVERS, j, N_WORKERS,
+                                  device_kind=jax.devices()[0].device_kind)
         rows.append((f"kern_tick_budget_us_j{j}", "",
                      f"{roof['budget_us']:.3f} us roofline "
                      f"({roof['bound']}-bound, "

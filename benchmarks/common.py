@@ -21,7 +21,8 @@ from repro.core import metrics
 DEFAULT_SEEDS = tuple(range(8))
 
 #: One entry per simulate/simulate_batch call since the last drain:
-#: scheduler, policy, params_hash, dropped, idle_worker_ticks, seconds[, seeds].
+#: scheduler, policy, params_hash, dropped, idle_worker_ticks, the resolved
+#: tick_impl (``"pallas"`` or ``"ref"``), seconds[, seeds].
 RUN_LOG: list[dict] = []
 
 

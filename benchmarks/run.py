@@ -23,6 +23,8 @@ artifact.
 """
 import sys
 
+from repro.compile_cache import enable_compile_cache
+
 from .bench_apps import run_fig13
 from .bench_batch import run_batch
 from .bench_comparison import run_fig12
@@ -122,6 +124,7 @@ def main() -> None:
     if "--list" in argv:
         list_sections()
         return
+    enable_compile_cache()
     json_path = workspace_root = None
     if "--json" in argv:
         i = argv.index("--json")

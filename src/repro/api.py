@@ -96,6 +96,9 @@ class RunResult:
     idle_worker_ticks: int        # workers idle while demand existed
     ticks: int
     state: object = dataclasses.field(default=None, repr=False)
+    #: Worker-phase implementation that ran (``"pallas"`` or ``"ref"``;
+    #: see :func:`repro.core.engine.resolve_tick_impl`).
+    tick_impl: Optional[str] = None
 
     # -- legacy dict-style access (repro.core.metrics helpers) ---------------
     def __getitem__(self, key):
@@ -164,6 +167,7 @@ class RunResult:
             "params_hash": self.params_hash(),
             "dropped": int(np.asarray(self.dropped).sum()),
             "idle_worker_ticks": int(np.asarray(self.idle_worker_ticks).sum()),
+            "tick_impl": self.tick_impl,
         }
 
 
@@ -209,7 +213,7 @@ class BatchRunResult(RunResult):
             issued=self.issued[k], completed=self.completed[k],
             dropped=int(self.dropped[k]),
             idle_worker_ticks=int(self.idle_worker_ticks[k]),
-            ticks=self.ticks)
+            ticks=self.ticks, tick_impl=self.tick_impl)
 
     def per_seed(self) -> list[RunResult]:
         return [self.seed_result(k) for k in range(self.n_seeds)]
@@ -242,6 +246,7 @@ class SweepResult:
     dropped: np.ndarray           # i32[P, K]
     idle_worker_ticks: np.ndarray  # i32[P, K]
     ticks: int
+    tick_impl: Optional[str] = None
 
     @property
     def n_points(self) -> int:
@@ -264,7 +269,7 @@ class SweepResult:
             gbps=self.gbps[i], bin_s=self.bin_s, issued=self.issued[i],
             completed=self.completed[i], dropped=self.dropped[i],
             idle_worker_ticks=self.idle_worker_ticks[i], ticks=self.ticks,
-            seeds=self.seeds)
+            tick_impl=self.tick_impl, seeds=self.seeds)
 
     def per_point(self) -> list[BatchRunResult]:
         return [self.point_result(i) for i in range(self.n_points)]
@@ -793,7 +798,8 @@ class Experiment:
             issued=raw["issued"], completed=raw["completed"],
             dropped=raw["dropped"],
             idle_worker_ticks=raw["idle_worker_ticks"],
-            ticks=raw["ticks"], state=raw["state"])
+            ticks=raw["ticks"], state=raw["state"],
+            tick_impl=raw["tick_impl"])
 
     def run_batch(self, seconds: float,
                   seeds: Sequence[int] = tuple(range(8))) -> BatchRunResult:
@@ -810,7 +816,8 @@ class Experiment:
             issued=raw["issued"], completed=raw["completed"],
             dropped=raw["dropped"],
             idle_worker_ticks=raw["idle_worker_ticks"],
-            ticks=raw["ticks"], state=raw["state"], seeds=raw["seeds"])
+            ticks=raw["ticks"], state=raw["state"],
+            tick_impl=raw["tick_impl"], seeds=raw["seeds"])
 
     def _expand_grid(self, grid) -> list[SchedulerParams]:
         """A grid is either a sequence of concrete params instances, or a
@@ -885,7 +892,8 @@ class Experiment:
             seconds=seconds, gbps=raw["gbps"], bin_s=raw["bin_s"],
             issued=raw["issued"], completed=raw["completed"],
             dropped=raw["dropped"],
-            idle_worker_ticks=raw["idle_worker_ticks"], ticks=raw["ticks"])
+            idle_worker_ticks=raw["idle_worker_ticks"], ticks=raw["ticks"],
+            tick_impl=raw["tick_impl"])
 
     def solo(self, job: int, seconds: float, *,
              workspace=None, name: str = "solo") -> RunResult:

@@ -1,0 +1,32 @@
+"""JAX's persistent compile cache, switched on by entry-point scripts.
+
+A chip run compiles the engine scan and the Pallas kernels once per shape;
+the persistent cache lets the next process (or the next run in the same
+checkout) load them instead.  Call :func:`enable_compile_cache` from a
+script's ``main`` — never at import, so library users and the test suite
+keep JAX's defaults.
+"""
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+import jax
+
+#: Fixed cache directory inside the checkout (listed in ``.gitignore``).
+#: The path is part of each entry's key, so it must not move between runs.
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compile cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its cache
+    there and no other path is set here; otherwise the cache goes to
+    :data:`CHECKOUT_CACHE_DIR`.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path

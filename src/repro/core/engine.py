@@ -53,7 +53,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from . import baselines
@@ -190,8 +189,9 @@ def resolve_tick_impl(cfg: "EngineConfig", sched: Scheduler) -> str:
     only on TPU backends.  A server-sharded run (``mesh_shape``/
     ``shard_servers`` splitting the ``[S]`` axis) always runs the scan: the
     sharded tick keeps ring buffers device-local, which the fused kernel's
-    monolithic ``[S, J, W]`` window does not — the fallback is silent, like
-    every other fallback here (no warning spam on accelerator-less rigs).
+    monolithic ``[S, J, W]`` window does not.  A fallback warns nobody, but
+    it is not hidden: :func:`run` / :func:`run_batch` report the resolved
+    impl, and every ``RunResult`` carries it.
     """
     impl = cfg.tick_impl
     if impl not in TICK_IMPLS:
@@ -373,6 +373,9 @@ def make_tick(cfg: EngineConfig, wl: Workload, table: JobTable, n_bins: int,
     srv_idx = jnp.arange(s_, dtype=jnp.int32)
     sched = get_scheduler(cfg.scheduler)
     tick_impl = resolve_tick_impl(cfg, sched)
+    # Hooks see the resolved impl: a scan-path draw (sharded runs, or an
+    # explicit "ref") must never re-resolve "auto" to the kernel itself.
+    cfg = dataclasses.replace(cfg, tick_impl=tick_impl)
     # Scenario geometry.  ``wl`` is concrete (a trace constant), so which
     # arrival machinery the tick needs is decided here in Python: a workload
     # with no open-loop phase traces the exact pre-scenario tick — same ops,
@@ -736,25 +739,10 @@ def make_tick(cfg: EngineConfig, wl: Workload, table: JobTable, n_bins: int,
     return tick_sharded
 
 
-def run(cfg: EngineConfig, wl: Workload, table: JobTable, sim_seconds: float):
-    """Run the simulation; returns the final state and per-bin throughput.
-
-    Args:
-      cfg: engine geometry + scheduler selection (static for the trace).
-      wl/table: from :func:`make_workload` — the phased client population
-        and the policy-attribute job table.
-      sim_seconds: simulated horizon; ``ticks = sim_seconds / cfg.dt``.
-
-    Returns a dict: ``state`` (final :class:`EngineState`), ``gbps[J, NB]``
-    (job j's throughput in GB/s per ``bin_s``-second bin), plus the
-    ``issued``/``completed``/``dropped``/``idle_worker_ticks`` counters.
-
-    With ``cfg.mesh_shape``/``shard_servers`` set, the scan runs under
-    ``shard_map`` with each device owning a server slab (see
-    :mod:`repro.core.shard`); results are bit-identical to the single-device
-    path.  A sweep axis in ``mesh_shape`` is idle here (one run has no grid
-    axis) — lanes replicate over it.
-    """
+def _run_program(cfg: EngineConfig, wl: Workload, table: JobTable,
+                 sim_seconds: float):
+    """The jitted whole-run program and its arguments: ``(fn, args, ticks)``
+    with ``fn(*args)`` the final :class:`EngineState`."""
     ticks = int(round(sim_seconds / cfg.dt))
     n_bins = max(1, (ticks + cfg.bin_ticks - 1) // cfg.bin_ticks)
     shard = resolve_shard(cfg)
@@ -772,11 +760,43 @@ def run(cfg: EngineConfig, wl: Workload, table: JobTable, sim_seconds: float):
         _run = jax.jit(_body)
     else:
         specs = state_specs(state, shard)
-        _run = jax.jit(shard_map(
-            _body, shard.mesh(), in_specs=(P(), specs), out_specs=specs,
-            check_rep=False))
+        _run = jax.jit(jax.shard_map(
+            _body, mesh=shard.mesh(), in_specs=(P(), specs), out_specs=specs,
+            check_vma=False))
+    return _run, (params, state), ticks
 
-    state = _run(params, state)
+
+def lower_run(cfg: EngineConfig, wl: Workload, table: JobTable,
+              sim_seconds: float) -> jax.stages.Lowered:
+    """The program :func:`run` executes, lowered but not run — e.g.
+    ``.compile().as_text()`` shows whether the fused kernel is in it."""
+    fn, args, _ = _run_program(cfg, wl, table, sim_seconds)
+    return fn.lower(*args)
+
+
+def run(cfg: EngineConfig, wl: Workload, table: JobTable, sim_seconds: float):
+    """Run the simulation; returns the final state and per-bin throughput.
+
+    Args:
+      cfg: engine geometry + scheduler selection (static for the trace).
+      wl/table: from :func:`make_workload` — the phased client population
+        and the policy-attribute job table.
+      sim_seconds: simulated horizon; ``ticks = sim_seconds / cfg.dt``.
+
+    Returns a dict: ``state`` (final :class:`EngineState`), ``gbps[J, NB]``
+    (job j's throughput in GB/s per ``bin_s``-second bin), the
+    ``issued``/``completed``/``dropped``/``idle_worker_ticks`` counters, and
+    ``tick_impl`` — the worker-phase implementation that actually ran
+    (:func:`resolve_tick_impl`).
+
+    With ``cfg.mesh_shape``/``shard_servers`` set, the scan runs under
+    ``shard_map`` with each device owning a server slab (see
+    :mod:`repro.core.shard`); results are bit-identical to the single-device
+    path.  A sweep axis in ``mesh_shape`` is idle here (one run has no grid
+    axis) — lanes replicate over it.
+    """
+    fn, args, ticks = _run_program(cfg, wl, table, sim_seconds)
+    state = fn(*args)
     bin_s = cfg.bin_ticks * cfg.dt
     return {
         "state": state,
@@ -787,6 +807,7 @@ def run(cfg: EngineConfig, wl: Workload, table: JobTable, sim_seconds: float):
         "dropped": int(state.dropped),
         "idle_worker_ticks": int(state.idle_worker_ticks),
         "ticks": ticks,
+        "tick_impl": resolve_tick_impl(cfg, get_scheduler(cfg.scheduler)),
     }
 
 
@@ -876,10 +897,10 @@ def run_batch(cfg: EngineConfig, wl: Workload, table: JobTable,
                     (grid_spec if points is None else P()),
                     (grid_spec if points is not None else P()),
                     state_specs(base, shard))
-        _run_all = jax.jit(shard_map(
-            _body, shard.mesh(), in_specs=in_specs,
+        _run_all = jax.jit(jax.shard_map(
+            _body, mesh=shard.mesh(), in_specs=in_specs,
             out_specs=state_specs(base, shard, lead=lead),
-            check_rep=False))
+            check_vma=False))
 
     state = _run_all(params, seed_arr, point_idx, base)
     bin_s = cfg.bin_ticks * cfg.dt
@@ -893,4 +914,5 @@ def run_batch(cfg: EngineConfig, wl: Workload, table: JobTable,
         "dropped": np.asarray(state.dropped),                # [(P,) K]
         "idle_worker_ticks": np.asarray(state.idle_worker_ticks),  # [(P,) K]
         "ticks": ticks,
+        "tick_impl": resolve_tick_impl(cfg, sched),
     }

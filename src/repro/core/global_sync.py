@@ -118,17 +118,15 @@ def make_sharded_sync(policy: Policy, mesh, axis: str = "data") -> Callable:
     the all-gather over ``axis`` implements the paper's controller sync (UCX
     all-gather -> ``jax.lax.all_gather``).
     """
-    from jax.experimental.shard_map import shard_map
-
     def _local(table: JobTable, demand_row: jnp.ndarray) -> jnp.ndarray:
         full = jax.lax.all_gather(demand_row, axis_name=axis, tiled=True)  # [S, J]
         segs = sync_segments(policy, table, full)
         idx = jax.lax.axis_index(axis) * demand_row.shape[0]
         return jax.lax.dynamic_slice_in_dim(segs, idx, demand_row.shape[0], axis=0)
 
-    return shard_map(
+    return jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(), P(axis)),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )

@@ -16,6 +16,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.prefix import prefix_sum
 from repro.kernels.token_select.ops import token_select
 
 
@@ -41,8 +42,12 @@ def shares_have_mass(shares: jnp.ndarray, demand: jnp.ndarray) -> jnp.ndarray:
 
 
 def segments(shares: jnp.ndarray) -> jnp.ndarray:
-    """Cumulative segment boundaries over [0, 1]; last entry == total mass."""
-    return jnp.cumsum(shares, axis=-1)
+    """Cumulative segment boundaries over [0, 1]; last entry == total mass.
+
+    The same log-step prefix sum the token kernels and their oracle run
+    (:func:`repro.kernels.prefix.prefix_sum`), so every segment table is
+    added in one order on every backend."""
+    return prefix_sum(jnp.asarray(shares))
 
 
 def select_job(shares: jnp.ndarray, demand: jnp.ndarray, u: jnp.ndarray,

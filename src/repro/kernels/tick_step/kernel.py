@@ -3,16 +3,16 @@
 The engine's hot path is a W-step ``lax.scan`` of O(S·J) gathers/scatters —
 per worker: mask demand, renormalize the share table, prefix-sum, segment
 search, pop, advance the ring head.  This kernel answers all W draws in ONE
-invocation: the ``[S, J]`` queue state lives in VMEM scratch and is mutated
-across the (statically unrolled) worker loop, so the share table is loaded
-once per server block instead of W times, and nothing round-trips to HBM
-between workers.
+invocation: the ``[S, J]`` queue state stays in VMEM across the (statically
+unrolled) worker loop, so the share table is loaded once per server block
+instead of W times, and nothing round-trips to HBM between workers.
 
 Two select modes are lowered (the capability the scheduler registry flags
 with ``Scheduler.kernel_tick``):
 
-  * ``themis`` — the statistical-token weighted draw, the *same op
-    sequence* as ``token_select`` / ``core.tokens.select_job``;
+  * ``themis`` — the statistical-token weighted draw: the body calls
+    :func:`repro.kernels.token_select.ref.weighted_draw`, the function
+    ``token_select`` / ``core.tokens.select_job`` draw through;
   * ``fifo``   — earliest queued arrival, over a precomputed ``[S, J, W]``
     window of the next W ring stamps (the at-most-W pops a tick can take).
 
@@ -28,68 +28,56 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..prefix import first_index
+from ..token_select.ref import weighted_draw
 from .ref import MODES
 
 
-def _themis_draw(shares, demand, u_w, real_j):
-    """One worker's weighted draw over a [BS, Jp] block — the op sequence of
-    ``token_select`` with the clip pinned to the real J (padding-exact)."""
-    dm = demand.astype(shares.dtype)
-    masked = shares * dm
-    total_m = jnp.sum(masked, axis=-1, keepdims=True)
-    probs = jnp.where(total_m > 0, masked / jnp.maximum(total_m, 1e-30), 0.0)
-    no_mass = jnp.sum(probs, axis=-1, keepdims=True) <= 0
-    ones_m = jnp.ones_like(shares) * dm
-    total_u = jnp.sum(ones_m, axis=-1, keepdims=True)
-    uniform = jnp.where(total_u > 0, ones_m / jnp.maximum(total_u, 1e-30), 0.0)
-    probs = jnp.where(no_mass, uniform, probs)
-    seg = jnp.cumsum(probs, axis=-1)
-    total = seg[:, -1]
-    idx = jnp.sum((seg <= u_w[:, None]).astype(jnp.int32), axis=-1)
-    idx = jnp.clip(idx, 0, real_j - 1)
-    idx = jnp.where(total > 0, idx, -1)
-    picked_ok = jnp.take_along_axis(
-        demand.astype(jnp.int32), jnp.maximum(idx, 0)[:, None], axis=-1)[:, 0]
-    first = jnp.argmax(demand.astype(jnp.int32), axis=-1).astype(jnp.int32)
-    return jnp.where((idx >= 0) & (picked_ok == 0), first, idx).astype(jnp.int32)
-
-
 def _tick_step_kernel(shares_ref, qcount_ref, window_ref, free_ref, u_ref,
-                      sel_ref, valid_ref, dany_ref, qout_ref, pops_ref,
-                      q_scr, p_scr, *, mode: str, real_j: int, n_workers: int):
+                      sel_ref, valid_ref, dany_ref, qout_ref, pops_ref, *,
+                      mode: str, real_j: int, n_workers: int):
     shares = shares_ref[...]                         # [BS, Jp]
-    window = window_ref[...]                         # [BS, Jp, W]
+    qcount = qcount_ref[...]                         # [BS, Jp] live counts
     free = free_ref[...] > 0                         # [BS, W]
     u = u_ref[...]                                   # [BS, W]
-    q_scr[...] = qcount_ref[...]                     # queue state -> scratch
-    p_scr[...] = jnp.zeros_like(qcount_ref[...])
-    kidx = jax.lax.broadcasted_iota(jnp.int32, window.shape, 2)
-    jidx = jax.lax.broadcasted_iota(jnp.int32, shares.shape, 1)
+    pops = jnp.zeros_like(qcount)                    # ring advance so far
+    jidx = jax.lax.broadcasted_iota(jnp.int32, qcount.shape, 1)
+    widx = jax.lax.broadcasted_iota(jnp.int32, u.shape, 1)
+    sel = jnp.zeros(u.shape, jnp.int32)
+    valid_all = jnp.zeros(u.shape, jnp.int32)
+    dany_all = jnp.zeros(u.shape, jnp.int32)
+    if mode == "fifo":
+        window = window_ref[...]                     # [BS, Jp, W]
+        kidx = jax.lax.broadcasted_iota(jnp.int32, window.shape, 2)
     for w in range(n_workers):                       # static unroll
-        qcount = q_scr[...]
-        pops = p_scr[...]
         demand = qcount > 0
+        dany = jnp.any(demand, axis=-1, keepdims=True)               # [BS, 1]
         if mode == "themis":
-            j_sel = _themis_draw(shares, demand, u[:, w], real_j)
+            j_sel = weighted_draw(
+                shares, demand, jax.lax.slice_in_dim(u, w, w + 1, axis=1),
+                real_j, roll=pltpu.roll)                             # [BS, 1]
         else:
             # branchless window gather at k = pops (a one-hot min; exactly
             # window[s, j, pops] — each k matches at most once)
             ht = jnp.min(jnp.where(kidx == pops[:, :, None], window, jnp.inf),
                          axis=-1)
             ht = jnp.where(demand, ht, jnp.inf)
-            j_sel = jnp.argmin(ht, axis=-1).astype(jnp.int32)
-            j_sel = jnp.where(demand.any(axis=-1), j_sel, -1)
-        valid = free[:, w] & (j_sel >= 0)
-        j_safe = jnp.maximum(j_sel, 0)
-        onehot = ((jidx == j_safe[:, None]).astype(jnp.int32)
-                  * valid[:, None].astype(jnp.int32))
-        q_scr[...] = qcount - onehot
-        p_scr[...] = pops + onehot
-        sel_ref[:, w] = j_sel
-        valid_ref[:, w] = valid.astype(jnp.int32)
-        dany_ref[:, w] = demand.any(axis=-1).astype(jnp.int32)
-    qout_ref[...] = q_scr[...]
-    pops_ref[...] = p_scr[...]
+            # earliest stamp, ties to the lowest job like jnp.argmin
+            j_sel = first_index(ht == jnp.min(ht, axis=-1, keepdims=True))
+            j_sel = jnp.where(dany, j_sel, -1)
+        valid = jax.lax.slice_in_dim(free, w, w + 1, axis=1) & (j_sel >= 0)
+        onehot = ((jidx == j_sel) & valid).astype(jnp.int32)
+        qcount = qcount - onehot
+        pops = pops + onehot
+        here = widx == w
+        sel = jnp.where(here, j_sel, sel)
+        valid_all = jnp.where(here, valid.astype(jnp.int32), valid_all)
+        dany_all = jnp.where(here, dany.astype(jnp.int32), dany_all)
+    sel_ref[...] = sel
+    valid_ref[...] = valid_all
+    dany_ref[...] = dany_all
+    qout_ref[...] = qcount
+    pops_ref[...] = pops
 
 
 def tick_step_pallas(shares: jnp.ndarray, qcount: jnp.ndarray,
@@ -109,7 +97,7 @@ def tick_step_pallas(shares: jnp.ndarray, qcount: jnp.ndarray,
     w = u.shape[1]
     jp = -(-j // 128) * 128
     sp = -(-s // block_servers) * block_servers
-    shares_p = jnp.zeros((sp, jp), shares.dtype).at[:s, :j].set(shares)
+    shares_p = jnp.zeros((sp, jp), jnp.float32).at[:s, :j].set(shares)
     qcount_p = jnp.zeros((sp, jp), jnp.int32).at[:s, :j].set(qcount)
     window_p = jnp.zeros((sp, jp, w), jnp.float32).at[:s, :j].set(window)
     free_p = jnp.zeros((sp, w), jnp.int32).at[:s].set(free.astype(jnp.int32))
@@ -140,10 +128,6 @@ def tick_step_pallas(shares: jnp.ndarray, qcount: jnp.ndarray,
             jax.ShapeDtypeStruct((sp, w), jnp.int32),
             jax.ShapeDtypeStruct((sp, jp), jnp.int32),
             jax.ShapeDtypeStruct((sp, jp), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bs, jp), jnp.int32),   # live queue counts
-            pltpu.VMEM((bs, jp), jnp.int32),   # pops so far (ring advance)
         ],
         interpret=interpret,
     )(shares_p, qcount_p, window_p, free_p, u_p)

@@ -7,17 +7,16 @@ TPU-native equivalent of the same statistics is a *fused masked weighted
 choice* over a fixed job-slot table:
 
     mask   = qcount > 0                       (opportunity fairness)
-    probs  = renorm(shares * mask)            (falls back to uniform over
-    seg    = inclusive prefix-sum(probs)       demanded jobs when massless)
-    pick   = count(seg <= u)
+    weight = shares * mask                    (uniform over demanded jobs
+    seg    = inclusive prefix-sum(weight)      when massless)
+    pick   = count(seg <= u * seg[J-1])
 
 One grid step processes a block of servers; the segment table lives in VMEM
 (jobs padded to the 128-lane width), and all W worker draws for the block are
-answered branchlessly in one pass.  ref.py is the pure-jnp oracle — the
-*same op sequence* as ``repro.core.tokens.select_job``, so the kernel is
-held to bit-identity with the engine's production draw path (trailing-zero
-padding is exact under the sequential CPU reductions interpret mode runs;
-the clip below uses the real J so padding never changes the pick).
+answered branchlessly in one pass.  The body is
+:func:`~.ref.weighted_draw` — the oracle's own function, with the lane
+rotation bound to ``pltpu.roll`` and the segment search clipped to the real
+J — so kernel and oracle are one op sequence and agree bit for bit.
 """
 from __future__ import annotations
 
@@ -26,40 +25,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .ref import weighted_draw
 
 
 def _token_select_kernel(shares_ref, qcount_ref, u_ref, out_ref, *,
                          real_j: int):
-    shares = shares_ref[...]                         # [BS, Jp]
-    qcount = qcount_ref[...]                         # [BS, Jp]
-    u = u_ref[...]                                   # [BS, W]
-    demand = qcount > 0
-    dm = demand.astype(shares.dtype)
-    masked = shares * dm
-    total_m = jnp.sum(masked, axis=-1, keepdims=True)
-    probs = jnp.where(total_m > 0, masked / jnp.maximum(total_m, 1e-30), 0.0)
-    # fall back to uniform-over-demanded when the policy gave no mass yet
-    no_mass = jnp.sum(probs, axis=-1, keepdims=True) <= 0
-    ones_m = jnp.ones_like(shares) * dm
-    total_u = jnp.sum(ones_m, axis=-1, keepdims=True)
-    uniform = jnp.where(total_u > 0, ones_m / jnp.maximum(total_u, 1e-30), 0.0)
-    probs = jnp.where(no_mass, uniform, probs)
-    seg = jnp.cumsum(probs, axis=-1)                 # [BS, Jp]
-    total = seg[:, -1]                               # [BS]
-    # segment search per worker draw; count boundaries <= u (branchless)
-    idx = jnp.sum((seg[:, None, :] <= u[:, :, None]).astype(jnp.int32),
-                  axis=-1)                           # [BS, W]
-    # clip against the REAL job count: a draw that lands past the last real
-    # segment (u at the rounding edge counts the flat padded tail too) must
-    # resolve exactly as the unpadded oracle resolves it.
-    idx = jnp.clip(idx, 0, real_j - 1)
-    idx = jnp.where(total[:, None] > 0, idx, -1)
-    # roundoff guard: picked slot must have demand; else first demanded slot
-    picked_ok = jnp.take_along_axis(demand.astype(jnp.int32),
-                                    jnp.maximum(idx, 0), axis=-1)
-    first = jnp.argmax(demand.astype(jnp.int32), axis=-1).astype(jnp.int32)
-    idx = jnp.where((idx >= 0) & (picked_ok == 0), first[:, None], idx)
-    out_ref[...] = idx.astype(jnp.int32)
+    out_ref[...] = weighted_draw(shares_ref[...], qcount_ref[...] > 0,
+                                 u_ref[...], real_j, roll=pltpu.roll)
 
 
 def token_select_pallas(shares: jnp.ndarray, qcount: jnp.ndarray,
@@ -75,7 +49,7 @@ def token_select_pallas(shares: jnp.ndarray, qcount: jnp.ndarray,
     w = u.shape[1]
     jp = -(-j // 128) * 128
     sp = -(-s // block_servers) * block_servers
-    shares_p = jnp.zeros((sp, jp), shares.dtype).at[:s, :j].set(shares)
+    shares_p = jnp.zeros((sp, jp), jnp.float32).at[:s, :j].set(shares)
     qcount_p = jnp.zeros((sp, jp), jnp.int32).at[:s, :j].set(qcount)
     u_p = jnp.zeros((sp, w), jnp.float32).at[:s].set(u)
     grid = (sp // block_servers,)

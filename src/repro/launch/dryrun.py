@@ -96,7 +96,9 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, overrides: dict | None 
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    report = analyze_compiled(cfg, shape, compiled, chips=chips)
+    # the production mesh models a v5e pod (repro.launch.mesh)
+    report = analyze_compiled(cfg, shape, compiled, chips=chips,
+                              device_kind="TPU v5 lite")
     report.update({
         "arch": arch, "shape": shape_name, "mesh": mesh_kind,
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),

@@ -1,10 +1,11 @@
 """Roofline analysis from compiled dry-run artifacts (no real hardware).
 
-Terms (per (arch, shape, mesh) cell), TPU v5e constants:
+Terms (per (arch, shape, mesh) cell), with the peaks of the device kind the
+cell targets (:data:`DEVICE_PEAKS`; an unknown kind is an error):
 
-    compute_s    = FLOPs_per_device / 197e12        (bf16 MXU peak per chip)
-    memory_s     = bytes_per_device / 819e9         (HBM bandwidth per chip)
-    collective_s = collective_bytes_per_device / 50e9   (per-link ICI)
+    compute_s    = FLOPs_per_device / peak_flops        (bf16 peak per chip)
+    memory_s     = bytes_per_device / hbm_bw            (HBM bandwidth)
+    collective_s = collective_bytes_per_device / link_bw   (per ICI link)
 
 ``compiled.cost_analysis()`` is evaluated on the SPMD-partitioned per-device
 module, so its flops/bytes are already per-device; dividing by per-chip peak
@@ -20,9 +21,25 @@ import re
 from typing import Any
 
 
-PEAK_FLOPS = 197e12     # bf16 per chip
-HBM_BW = 819e9          # bytes/s per chip
-LINK_BW = 50e9          # bytes/s per ICI link
+_V5E = {
+    "flops": 197e12,     # bf16 FLOP/s per chip
+    "hbm_bw": 819e9,     # HBM bytes/s per chip
+    "link_bw": 50e9,     # bytes/s per ICI link (1,600 Gbit/s over 4 links)
+    "source": 'Google Cloud documentation, "TPU v5e"',
+}
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``, with source.
+DEVICE_PEAKS = {"TPU v5 lite": _V5E, "TPU v5e": _V5E}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The published peaks of ``device_kind``; no default for other kinds."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -73,7 +90,8 @@ def collective_bytes_from_hlo(hlo_text: str) -> dict[str, Any]:
     return {"per_kind_bytes": out, "per_kind_count": counts, "total_bytes": total}
 
 
-def tick_step_roofline(s: int, j: int, w: int, dtype_bytes: int = 4) -> dict:
+def tick_step_roofline(s: int, j: int, w: int, *, device_kind: str,
+                       dtype_bytes: int = 4) -> dict:
     """Analytic roofline for one fused tick-step invocation
     (:mod:`repro.kernels.tick_step`) at geometry ``[S, J]`` × ``W`` workers.
 
@@ -100,8 +118,9 @@ def tick_step_roofline(s: int, j: int, w: int, dtype_bytes: int = 4) -> dict:
     bytes_out = s * (3 * w + 2 * j) * dtype_bytes
     bytes_total = bytes_in + bytes_out
     flops = s * w * j * 12.0
-    memory_s = bytes_total / HBM_BW
-    compute_s = flops / PEAK_FLOPS
+    peaks = device_peaks(device_kind)
+    memory_s = bytes_total / peaks["hbm_bw"]
+    compute_s = flops / peaks["flops"]
     return {
         "s": s, "j": j, "w": w,
         "bytes": bytes_total,
@@ -127,7 +146,8 @@ def model_flops(cfg, shape) -> float:
     return 2.0 * n * shape.global_batch
 
 
-def analyze_compiled(cfg, shape, compiled, chips: int) -> dict:
+def analyze_compiled(cfg, shape, compiled, chips: int, *,
+                     device_kind: str) -> dict:
     from .hlo_parse import analyze_hlo
 
     try:
@@ -152,9 +172,10 @@ def analyze_compiled(cfg, shape, compiled, chips: int) -> dict:
         "total_bytes": acc.get("collective_total_bytes", 0.0),
     }
 
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_acc / HBM_BW
-    collective_s = coll["total_bytes"] / LINK_BW
+    peaks = device_peaks(device_kind)
+    compute_s = flops / peaks["flops"]
+    memory_s = bytes_acc / peaks["hbm_bw"]
+    collective_s = coll["total_bytes"] / peaks["link_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
 
@@ -163,7 +184,8 @@ def analyze_compiled(cfg, shape, compiled, chips: int) -> dict:
     useful_ratio = mf_per_device / flops if flops else 0.0
     # roofline fraction: useful model flops per device per bound-step-time
     step_time = max(terms.values())
-    roofline_frac = (mf_per_device / PEAK_FLOPS) / step_time if step_time else 0.0
+    roofline_frac = ((mf_per_device / peaks["flops"]) / step_time
+                     if step_time else 0.0)
 
     mem = {}
     try:

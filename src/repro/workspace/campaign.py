@@ -128,6 +128,7 @@ def _point_payload(sub, j: int) -> dict:
         "ticks": int(sub.ticks),
         "seconds": float(sub.seconds),
         "n_jobs": int(sub.n_jobs),
+        "tick_impl": sub.tick_impl,
         "seeds": [int(s) for s in np.asarray(sub.seeds)],
         "params": {f: float(getattr(sub.points[j], f))
                    for f in sub.points[j].numeric_fields()},
@@ -227,7 +228,7 @@ def _merge(exp, points, seconds, seeds, payloads: dict[int, dict]):
         issued=stack("issued"), completed=stack("completed"),
         dropped=stack("dropped"),
         idle_worker_ticks=stack("idle_worker_ticks"),
-        ticks=int(first["ticks"]))
+        ticks=int(first["ticks"]), tick_impl=first.get("tick_impl"))
 
 
 # -- cached single runs -------------------------------------------------------
@@ -254,7 +255,7 @@ def run_cached(exp, seconds, *, store: WorkspaceStore, name: str):
             "dropped": int(res.dropped),
             "idle_worker_ticks": int(res.idle_worker_ticks),
             "ticks": int(res.ticks), "seconds": float(res.seconds),
-            "n_jobs": int(res.n_jobs)})
+            "n_jobs": int(res.n_jobs), "tick_impl": res.tick_impl})
         store.put(rec)
     p = rec.payload
     return RunResult(
@@ -263,4 +264,5 @@ def run_cached(exp, seconds, *, store: WorkspaceStore, name: str):
         n_jobs=int(p["n_jobs"]), seconds=float(p["seconds"]),
         gbps=p["gbps"], bin_s=float(p["bin_s"]), issued=p["issued"],
         completed=p["completed"], dropped=int(p["dropped"]),
-        idle_worker_ticks=int(p["idle_worker_ticks"]), ticks=int(p["ticks"]))
+        idle_worker_ticks=int(p["idle_worker_ticks"]), ticks=int(p["ticks"]),
+        tick_impl=p.get("tick_impl"))

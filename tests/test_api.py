@@ -144,7 +144,8 @@ class TestRunResult:
         import json
         c = res.counters()
         assert set(c) == {"scheduler", "policy", "params_hash", "dropped",
-                          "idle_worker_ticks"}
+                          "idle_worker_ticks", "tick_impl"}
+        assert c["tick_impl"] in ("ref", "pallas")
         json.dumps(c)
 
 

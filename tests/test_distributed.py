@@ -115,7 +115,6 @@ class TestMultiDeviceExecution:
         out = run_multidevice("""
             import jax, jax.numpy as jnp, numpy as np
             from jax.sharding import Mesh, PartitionSpec as P
-            from jax.experimental.shard_map import shard_map
             from repro.distributed.compression import (
                 compressed_psum_tree, init_error_feedback)
             devs = np.array(jax.devices()[:4])
@@ -130,9 +129,10 @@ class TestMultiDeviceExecution:
                 return {"w": gh["w"][None]}, {"w": ne["w"][None]}
 
             with mesh:
-                fn = shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
-                               out_specs=(P("data"), P("data")),
-                               check_rep=False)
+                fn = jax.shard_map(f, mesh=mesh,
+                                   in_specs=(P("data"), P("data")),
+                                   out_specs=(P("data"), P("data")),
+                                   check_vma=False)
                 # accumulate over steps: compressed mean must track the
                 # exact fp32 mean (error feedback corrects quantization)
                 exact = np.asarray(g["w"]).mean(0)
