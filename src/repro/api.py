@@ -59,10 +59,11 @@ import dataclasses
 import itertools
 from typing import Iterable, Mapping, Optional, Sequence
 
+import jax
 import numpy as np
 
 from repro.bb.service import BBClient, BBCluster, JobMeta, phase_at
-from repro.core import metrics
+from repro.core import metrics, spans
 from repro.core.engine import (EngineConfig, make_workload, normalize_phases,
                                run, run_batch)
 from repro.core.params import SchedulerParams
@@ -99,6 +100,11 @@ class RunResult:
     #: Worker-phase implementation that ran (``"pallas"`` or ``"ref"``;
     #: see :func:`repro.core.engine.resolve_tick_impl`).
     tick_impl: Optional[str] = None
+    #: Counters of the engine call that produced this result (``lanes``,
+    #: ``kernel_invocations``, ``jit_traces``, ``compile_cache_requests``,
+    #: ``compile_cache_hits``); empty where no engine call ran (a result
+    #: read back from a workspace).
+    call_counters: Mapping = dataclasses.field(default_factory=dict)
 
     # -- legacy dict-style access (repro.core.metrics helpers) ---------------
     def __getitem__(self, key):
@@ -160,7 +166,11 @@ class RunResult:
         return self.params.params_hash()
 
     def counters(self) -> dict:
-        """The attribution block BENCH_*.json artifacts embed per run."""
+        """The attribution block BENCH_*.json artifacts embed per run: what
+        ran, and the counters of the engine call (``ticks`` simulated,
+        ``lanes`` advanced together, ``kernel_invocations`` of the fused
+        tick step, ``jit_traces`` of the engine scan, and the persistent
+        compile cache's ``compile_cache_requests``/``compile_cache_hits``)."""
         return {
             "scheduler": self.scheduler,
             "policy": self.policy,
@@ -168,6 +178,8 @@ class RunResult:
             "dropped": int(np.asarray(self.dropped).sum()),
             "idle_worker_ticks": int(np.asarray(self.idle_worker_ticks).sum()),
             "tick_impl": self.tick_impl,
+            "ticks": int(self.ticks),
+            **self.call_counters,
         }
 
 
@@ -213,7 +225,8 @@ class BatchRunResult(RunResult):
             issued=self.issued[k], completed=self.completed[k],
             dropped=int(self.dropped[k]),
             idle_worker_ticks=int(self.idle_worker_ticks[k]),
-            ticks=self.ticks, tick_impl=self.tick_impl)
+            ticks=self.ticks, tick_impl=self.tick_impl,
+            call_counters=self.call_counters)
 
     def per_seed(self) -> list[RunResult]:
         return [self.seed_result(k) for k in range(self.n_seeds)]
@@ -789,7 +802,8 @@ class Experiment:
         """One jitted engine run -> :class:`RunResult`."""
         if not self.jobs:
             raise ValueError("run() needs at least one add_job()")
-        cfg, wl, table = self.build()
+        with jax.profiler.TraceAnnotation(spans.EXPERIMENT_BUILD):
+            cfg, wl, table = self.build()
         raw = run(cfg, wl, table, seconds)
         return RunResult(
             scheduler=self.scheduler, params=self.sched.params(cfg),
@@ -799,7 +813,7 @@ class Experiment:
             dropped=raw["dropped"],
             idle_worker_ticks=raw["idle_worker_ticks"],
             ticks=raw["ticks"], state=raw["state"],
-            tick_impl=raw["tick_impl"])
+            tick_impl=raw["tick_impl"], call_counters=raw["counters"])
 
     def run_batch(self, seconds: float,
                   seeds: Sequence[int] = tuple(range(8))) -> BatchRunResult:
@@ -807,7 +821,8 @@ class Experiment:
         (each lane bit-identical to ``run()`` with that seed)."""
         if not self.jobs:
             raise ValueError("run_batch() needs at least one add_job()")
-        cfg, wl, table = self.build()
+        with jax.profiler.TraceAnnotation(spans.EXPERIMENT_BUILD):
+            cfg, wl, table = self.build()
         raw = run_batch(cfg, wl, table, seconds, seeds=seeds)
         return BatchRunResult(
             scheduler=self.scheduler, params=self.sched.params(cfg),
@@ -817,7 +832,8 @@ class Experiment:
             dropped=raw["dropped"],
             idle_worker_ticks=raw["idle_worker_ticks"],
             ticks=raw["ticks"], state=raw["state"],
-            tick_impl=raw["tick_impl"], seeds=raw["seeds"])
+            tick_impl=raw["tick_impl"], call_counters=raw["counters"],
+            seeds=raw["seeds"])
 
     def _expand_grid(self, grid) -> list[SchedulerParams]:
         """A grid is either a sequence of concrete params instances, or a
@@ -883,7 +899,8 @@ class Experiment:
         if not self.jobs:
             raise ValueError("sweep() needs at least one add_job()")
         points = self._expand_grid(grid)
-        cfg, wl, table = self.build()
+        with jax.profiler.TraceAnnotation(spans.EXPERIMENT_BUILD):
+            cfg, wl, table = self.build()
         raw = run_batch(cfg, wl, table, seconds, seeds=seeds,
                         params_points=points)
         return SweepResult(

@@ -63,6 +63,8 @@ from .policy import Policy
 from .scheduler import Scheduler, TickView, get_scheduler
 from .shard import (AXIS_SERVERS, AXIS_SWEEP, ShardSpec, resolve_shard,
                     state_specs)
+from . import spans
+from repro.compile_cache import cache_events
 from repro.kernels.tick_step import tick_step
 
 #: One entry is appended each time an engine scan is traced for XLA.
@@ -400,146 +402,154 @@ def make_tick(cfg: EngineConfig, wl: Workload, table: JobTable, n_bins: int,
     fresh_start = jnp.asarray(~contig)                             # [J, P]
 
     def tick(p, state: EngineState, _):
-        ctrl = sched.ctrl_overhead_s(p)
-        t = state.t
-        t_sec = t.astype(jnp.float32) * cfg.dt
-        started = (t >= wl.phase_start) & phase_real               # [J, P]
-        phase_live = started & (t < wl.phase_end)
-        live = phase_live.any(axis=1)
-        # Current phase = most recently *started* real phase (held across
-        # idle gaps so a leftover backlog keeps its request profile); 0
-        # before any phase starts (no demand exists yet anyway).
-        cur = jnp.maximum(jnp.max(jnp.where(started, phase_idx, -1),
-                                  axis=1), 0)
-        take_cur = lambda a: jnp.take_along_axis(a, cur[:, None], axis=1)[:, 0]
-        req_now = take_cur(wl.phase_req)                           # f32[J]
-        think_now = take_cur(wl.phase_think)                       # i32[J]
-        recycle = live & (take_cur(wl.arrival_mode) == ARRIVAL_CLOSED)
+        with jax.named_scope(spans.TICK_ARRIVALS):
+            t = state.t
+            t_sec = t.astype(jnp.float32) * cfg.dt
+            started = (t >= wl.phase_start) & phase_real               # [J, P]
+            phase_live = started & (t < wl.phase_end)
+            live = phase_live.any(axis=1)
+            # Current phase = most recently *started* real phase (held across
+            # idle gaps so a leftover backlog keeps its request profile); 0
+            # before any phase starts (no demand exists yet anyway).
+            cur = jnp.maximum(jnp.max(jnp.where(started, phase_idx, -1),
+                                      axis=1), 0)
+            take_cur = lambda a: jnp.take_along_axis(a, cur[:, None], axis=1)[:, 0]
+            req_now = take_cur(wl.phase_req)                           # f32[J]
+            think_now = take_cur(wl.phase_think)                       # i32[J]
+            recycle = live & (take_cur(wl.arrival_mode) == ARRIVAL_CLOSED)
 
-        # -- 1. arrivals: time-wheel slot + phase starts + open-loop --------
-        slot = jnp.mod(t, h_)
-        inject = ((t == wl.phase_start) & phase_real & fresh_start
-                  & (wl.arrival_mode == ARRIVAL_CLOSED)).any(axis=1)
-        if has_interval:
-            gap = jnp.mod(t - wl.phase_start,
-                          jnp.maximum(wl.arrival_every, 1))
-            inject = inject | (phase_live & (gap == 0)
-                               & (wl.arrival_mode == ARRIVAL_INTERVAL)
-                               ).any(axis=1)
-        arrivals = state.wheel[:, :, slot] + jnp.where(
-            inject[None, :], wl.procs, 0)
-        key_carry = state.key
-        if has_poisson:
-            key_carry, kp = jax.random.split(state.key)
-            lam = jnp.where(
-                phase_live & (wl.arrival_mode == ARRIVAL_POISSON),
-                wl.arrival_rate, 0.0).sum(axis=1)                  # f32[J]
-            arrivals = arrivals + jax.random.poisson(
-                kp, lam[None, :] * wl.procs).astype(jnp.int32)
-        state = state._replace(wheel=state.wheel.at[:, :, slot].set(0))
-        state = _push_arrivals(state, arrivals, t_sec)
+            # -- 1. arrivals: time-wheel slot + phase starts + open-loop --------
+            slot = jnp.mod(t, h_)
+            inject = ((t == wl.phase_start) & phase_real & fresh_start
+                      & (wl.arrival_mode == ARRIVAL_CLOSED)).any(axis=1)
+            if has_interval:
+                gap = jnp.mod(t - wl.phase_start,
+                              jnp.maximum(wl.arrival_every, 1))
+                inject = inject | (phase_live & (gap == 0)
+                                   & (wl.arrival_mode == ARRIVAL_INTERVAL)
+                                   ).any(axis=1)
+            arrivals = state.wheel[:, :, slot] + jnp.where(
+                inject[None, :], wl.procs, 0)
+            key_carry = state.key
+            if has_poisson:
+                key_carry, kp = jax.random.split(state.key)
+                lam = jnp.where(
+                    phase_live & (wl.arrival_mode == ARRIVAL_POISSON),
+                    wl.arrival_rate, 0.0).sum(axis=1)                  # f32[J]
+                arrivals = arrivals + jax.random.poisson(
+                    kp, lam[None, :] * wl.procs).astype(jnp.int32)
+            state = state._replace(wheel=state.wheel.at[:, :, slot].set(0))
+            state = _push_arrivals(state, arrivals, t_sec)
 
-        # -- 2. scheduler bookkeeping --------------------------------------
-        aux = sched.pre_tick(cfg, p, state.aux, state.qcount, t)
-        shares = sched.tick_shares(cfg, table, TickView(
-            qcount=state.qcount, known=state.known, seg=state.seg,
-            synced=state.synced, live=live))
+        with jax.named_scope(spans.TICK_SCHED):
+            # -- 2. scheduler bookkeeping --------------------------------------
+            ctrl = sched.ctrl_overhead_s(p)
+            aux = sched.pre_tick(cfg, p, state.aux, state.qcount, t)
+            shares = sched.tick_shares(cfg, table, TickView(
+                qcount=state.qcount, known=state.known, seg=state.seg,
+                synced=state.synced, live=live))
 
-        # -- 3. workers: sequential pops within the tick --------------------
-        key, sub = jax.random.split(key_carry)
-        bytes_job = jnp.zeros((j_,), jnp.float32)
-        pops_job = jnp.zeros((j_,), jnp.int32)
-        idle_ticks = jnp.zeros((), jnp.int32)
+        with jax.named_scope(spans.TICK_WORKERS):
+            # -- 3. workers: sequential pops within the tick --------------------
+            key, sub = jax.random.split(key_carry)
+            bytes_job = jnp.zeros((j_,), jnp.float32)
+            pops_job = jnp.zeros((j_,), jnp.int32)
+            idle_ticks = jnp.zeros((), jnp.int32)
 
-        if tick_impl == "pallas":
-            # Fused path: all W draws in one tick-step kernel invocation.
-            # PRNG stream identity: the per-worker uniforms are precomputed
-            # with the exact fold_in/uniform sequence the scan's select hook
-            # consumes, so the run's key trajectory is unchanged.  Each
-            # worker only ever reads/writes its own free_at column and
-            # arr_time is read-only across the phase, so free/window can be
-            # materialized up front; a worker pops at ring offset pops[s,j]
-            # < W, which is why a [S, J, W] window covers every draw.
-            free = state.free_at < t_sec + cfg.dt                  # [S, W]
-            u_all = jnp.stack(
-                [jax.random.uniform(jax.random.fold_in(sub, w), (s_,))
-                 for w in range(w_)], axis=1)                      # [S, W]
-            koff = jnp.arange(w_, dtype=jnp.int32)[None, None, :]
-            ring_idx = jnp.mod(state.head[..., None] + koff, cap)
-            window = jnp.take_along_axis(state.arr_time, ring_idx, axis=-1)
-            sel, valid, demand_any, qcount, pops_sj = tick_step(
-                shares, state.qcount, window, free, u_all,
-                mode=sched.kernel_select_mode, impl="pallas")
-            head = jnp.mod(state.head + pops_sj, cap)
-            arr_time = state.arr_time
-            j_safe = jnp.maximum(sel, 0)                           # [S, W]
-            rb = req_now[j_safe]
-            service = rb / worker_bw + wl.overhead_s[j_safe] + ctrl
-            start_t = jnp.maximum(state.free_at, t_sec)
-            free_at = jnp.where(valid, start_t + service, state.free_at)
-            off = jnp.clip(
-                jnp.ceil((free_at - t_sec) / cfg.dt).astype(jnp.int32)
-                + think_now[j_safe], 1, h_ - 1)
-            slot2 = jnp.mod(t + off, h_)
-            live_add = (valid & recycle[j_safe]).astype(jnp.int32)
-            add_b = jnp.where(valid, rb, 0.0)
-            wheel = state.wheel
-            # Per-worker scatter order preserved (float adds must replay the
-            # scan's accumulation order bit-for-bit).
-            for w in range(w_):
-                wheel = wheel.at[srv_idx, j_safe[:, w], slot2[:, w]].add(
-                    live_add[:, w])
-                bytes_job = bytes_job.at[j_safe[:, w]].add(add_b[:, w])
-                pops_job = pops_job.at[j_safe[:, w]].add(
-                    valid[:, w].astype(jnp.int32))
-            idle_ticks = (free & ~valid & demand_any).sum().astype(jnp.int32)
-            # Lowered schedulers have the base no-op charge (checked by
-            # resolve_tick_impl), so aux passes through from pre_tick.
-            carry = (qcount, head, arr_time, wheel, free_at, aux, bytes_job,
-                     pops_job, idle_ticks)
-            return _finish(state, carry, key, t, live)
+            if tick_impl == "pallas":
+                # Fused path: all W draws in one tick-step kernel invocation.
+                # PRNG stream identity: the per-worker uniforms are precomputed
+                # with the exact fold_in/uniform sequence the scan's select hook
+                # consumes, so the run's key trajectory is unchanged.  Each
+                # worker only ever reads/writes its own free_at column and
+                # arr_time is read-only across the phase, so free/window can be
+                # materialized up front; a worker pops at ring offset pops[s,j]
+                # < W, which is why a [S, J, W] window covers every draw.
+                free = state.free_at < t_sec + cfg.dt                  # [S, W]
+                u_all = jnp.stack(
+                    [jax.random.uniform(jax.random.fold_in(sub, w), (s_,))
+                     for w in range(w_)], axis=1)                      # [S, W]
+                koff = jnp.arange(w_, dtype=jnp.int32)[None, None, :]
+                ring_idx = jnp.mod(state.head[..., None] + koff, cap)
+                window = jnp.take_along_axis(state.arr_time, ring_idx, axis=-1)
+                sel, valid, demand_any, qcount, pops_sj = tick_step(
+                    shares, state.qcount, window, free, u_all,
+                    mode=sched.kernel_select_mode, impl="pallas")
+                head = jnp.mod(state.head + pops_sj, cap)
+                arr_time = state.arr_time
+                j_safe = jnp.maximum(sel, 0)                           # [S, W]
+                rb = req_now[j_safe]
+                service = rb / worker_bw + wl.overhead_s[j_safe] + ctrl
+                start_t = jnp.maximum(state.free_at, t_sec)
+                free_at = jnp.where(valid, start_t + service, state.free_at)
+                off = jnp.clip(
+                    jnp.ceil((free_at - t_sec) / cfg.dt).astype(jnp.int32)
+                    + think_now[j_safe], 1, h_ - 1)
+                slot2 = jnp.mod(t + off, h_)
+                live_add = (valid & recycle[j_safe]).astype(jnp.int32)
+                add_b = jnp.where(valid, rb, 0.0)
+                wheel = state.wheel
+                # Per-worker scatter order preserved (float adds must replay the
+                # scan's accumulation order bit-for-bit).
+                for w in range(w_):
+                    wheel = wheel.at[srv_idx, j_safe[:, w], slot2[:, w]].add(
+                        live_add[:, w])
+                    bytes_job = bytes_job.at[j_safe[:, w]].add(add_b[:, w])
+                    pops_job = pops_job.at[j_safe[:, w]].add(
+                        valid[:, w].astype(jnp.int32))
+                idle_ticks = (free & ~valid & demand_any).sum().astype(jnp.int32)
+                # Lowered schedulers have the base no-op charge (checked by
+                # resolve_tick_impl), so aux passes through from pre_tick.
+                carry = (qcount, head, arr_time, wheel, free_at, aux, bytes_job,
+                         pops_job, idle_ticks)
+            else:
+                def worker_body(carry, w):
+                    (qcount, head, arr_time, wheel, free_at, aux, bytes_job,
+                     pops_job, idle_ticks) = carry
+                    kw = jax.random.fold_in(sub, w)
+                    free = free_at[:, w] < t_sec + cfg.dt
+                    demand = qcount > 0
+                    head_time = jnp.where(
+                        demand,
+                        jnp.take_along_axis(arr_time, (head % cap)[..., None],
+                                            axis=-1)[..., 0],
+                        jnp.inf)
+                    j_sel = sched.select(cfg, p, shares, head_time, demand, aux,
+                                         req_now, kw)
+                    valid = free & (j_sel >= 0)
+                    j_safe = jnp.maximum(j_sel, 0)
+                    onehot = (jax.nn.one_hot(j_safe, j_, dtype=jnp.int32)
+                              * valid[:, None].astype(jnp.int32))
+                    qcount = qcount - onehot
+                    head = jnp.mod(head + onehot, cap)
+                    rb = req_now[j_safe]
+                    service = rb / worker_bw + wl.overhead_s[j_safe] + ctrl
+                    start_t = jnp.maximum(free_at[:, w], t_sec)
+                    new_free = jnp.where(valid, start_t + service, free_at[:, w])
+                    free_at = free_at.at[:, w].set(new_free)
+                    # closed-loop re-arrival after completion + think time
+                    # (open-loop phases generate arrivals in step 1 instead
+                    # of recycling pops)
+                    job_live = recycle[j_safe]
+                    off = (jnp.ceil((new_free - t_sec) / cfg.dt).astype(jnp.int32)
+                           + think_now[j_safe])
+                    off = jnp.clip(off, 1, h_ - 1)
+                    slot2 = jnp.mod(t + off, h_)
+                    wheel = wheel.at[srv_idx, j_safe, slot2].add(
+                        (valid & job_live).astype(jnp.int32))
+                    add_b = jnp.where(valid, rb, 0.0)
+                    bytes_job = bytes_job.at[j_safe].add(add_b)
+                    pops_job = pops_job.at[j_safe].add(valid.astype(jnp.int32))
+                    aux = sched.charge(cfg, p, aux, srv_idx, j_safe, add_b)
+                    idle_ticks = idle_ticks + (
+                        free & ~valid & demand.any(axis=1)).sum().astype(jnp.int32)
+                    return (qcount, head, arr_time, wheel, free_at, aux,
+                            bytes_job, pops_job, idle_ticks), None
 
-        def worker_body(carry, w):
-            (qcount, head, arr_time, wheel, free_at, aux, bytes_job, pops_job,
-             idle_ticks) = carry
-            kw = jax.random.fold_in(sub, w)
-            free = free_at[:, w] < t_sec + cfg.dt
-            demand = qcount > 0
-            head_time = jnp.where(
-                demand,
-                jnp.take_along_axis(arr_time, (head % cap)[..., None], axis=-1)[..., 0],
-                jnp.inf)
-            j_sel = sched.select(cfg, p, shares, head_time, demand, aux,
-                                 req_now, kw)
-            valid = free & (j_sel >= 0)
-            j_safe = jnp.maximum(j_sel, 0)
-            onehot = jax.nn.one_hot(j_safe, j_, dtype=jnp.int32) * valid[:, None].astype(jnp.int32)
-            qcount = qcount - onehot
-            head = jnp.mod(head + onehot, cap)
-            rb = req_now[j_safe]
-            service = rb / worker_bw + wl.overhead_s[j_safe] + ctrl
-            start_t = jnp.maximum(free_at[:, w], t_sec)
-            new_free = jnp.where(valid, start_t + service, free_at[:, w])
-            free_at = free_at.at[:, w].set(new_free)
-            # closed-loop re-arrival after completion + think time (open-loop
-            # phases generate arrivals in step 1 instead of recycling pops)
-            job_live = recycle[j_safe]
-            off = jnp.ceil((new_free - t_sec) / cfg.dt).astype(jnp.int32) + think_now[j_safe]
-            off = jnp.clip(off, 1, h_ - 1)
-            slot2 = jnp.mod(t + off, h_)
-            wheel = wheel.at[srv_idx, j_safe, slot2].add(
-                (valid & job_live).astype(jnp.int32))
-            add_b = jnp.where(valid, rb, 0.0)
-            bytes_job = bytes_job.at[j_safe].add(add_b)
-            pops_job = pops_job.at[j_safe].add(valid.astype(jnp.int32))
-            aux = sched.charge(cfg, p, aux, srv_idx, j_safe, add_b)
-            idle_ticks = idle_ticks + (free & ~valid & demand.any(axis=1)).sum().astype(jnp.int32)
-            return (qcount, head, arr_time, wheel, free_at, aux, bytes_job,
-                    pops_job, idle_ticks), None
-
-        carry = (state.qcount, state.head, state.arr_time, state.wheel,
-                 state.free_at, aux, bytes_job, pops_job, idle_ticks)
-        carry, _ = jax.lax.scan(worker_body, carry, jnp.arange(w_, dtype=jnp.int32))
+                carry = (state.qcount, state.head, state.arr_time, state.wheel,
+                         state.free_at, aux, bytes_job, pops_job, idle_ticks)
+                carry, _ = jax.lax.scan(worker_body, carry,
+                                        jnp.arange(w_, dtype=jnp.int32))
         return _finish(state, carry, key, t, live)
 
     def _finish(state: EngineState, carry, key, t, live):
@@ -548,14 +558,15 @@ def make_tick(cfg: EngineConfig, wl: Workload, table: JobTable, n_bins: int,
         (qcount, head, arr_time, wheel, free_at, aux, bytes_job, pops_job,
          idle_ticks) = carry
 
-        b = jnp.minimum(t // cfg.bin_ticks, n_bins - 1)
-        state = state._replace(
-            t=t + 1, key=key, qcount=qcount, head=head, arr_time=arr_time,
-            wheel=wheel, free_at=free_at, aux=aux,
-            bytes_bin=state.bytes_bin.at[:, b].add(bytes_job),
-            completed=state.completed + pops_job,
-            idle_worker_ticks=state.idle_worker_ticks + idle_ticks,
-        )
+        with jax.named_scope(spans.TICK_FINISH):
+            b = jnp.minimum(t // cfg.bin_ticks, n_bins - 1)
+            state = state._replace(
+                t=t + 1, key=key, qcount=qcount, head=head, arr_time=arr_time,
+                wheel=wheel, free_at=free_at, aux=aux,
+                bytes_bin=state.bytes_bin.at[:, b].add(bytes_job),
+                completed=state.completed + pops_job,
+                idle_worker_ticks=state.idle_worker_ticks + idle_ticks,
+            )
 
         # -- 4. λ-delayed global fairness sync ------------------------------
         if sched.uses_segments and cfg.sync_ticks > 0:
@@ -564,8 +575,9 @@ def make_tick(cfg: EngineConfig, wl: Workload, table: JobTable, n_bins: int,
                 seg = sync_segments(cfg.policy, table, support,
                                     n_iters=cfg.sinkhorn_iters)
                 return st._replace(seg=seg, synced=support.any(axis=0))
-            state = jax.lax.cond(
-                jnp.mod(state.t, cfg.sync_ticks) == 0, do_sync, lambda s: s, state)
+            with jax.named_scope(spans.TICK_SYNC):
+                state = jax.lax.cond(jnp.mod(state.t, cfg.sync_ticks) == 0,
+                                     do_sync, lambda s: s, state)
         return state, None
 
     if shard is None or shard.n_servers == 1:
@@ -583,136 +595,140 @@ def make_tick(cfg: EngineConfig, wl: Workload, table: JobTable, n_bins: int,
         float-scatter order — so each device independently reaches the same
         global decisions and only *applies* its own slab's rows.
         """
-        row0 = jax.lax.axis_index(AXIS_SERVERS).astype(jnp.int32) * s_loc
-
         def gat(x):
             return jax.lax.all_gather(x, AXIS_SERVERS, axis=0, tiled=True)
 
         def rows(x):
             return jax.lax.dynamic_slice_in_dim(x, row0, s_loc, axis=0)
 
-        ctrl = sched.ctrl_overhead_s(p)
-        t = state.t
-        t_sec = t.astype(jnp.float32) * cfg.dt
-        started = (t >= wl.phase_start) & phase_real
-        phase_live = started & (t < wl.phase_end)
-        live = phase_live.any(axis=1)
-        cur = jnp.maximum(jnp.max(jnp.where(started, phase_idx, -1),
-                                  axis=1), 0)
-        take_cur = lambda a: jnp.take_along_axis(a, cur[:, None], axis=1)[:, 0]
-        req_now = take_cur(wl.phase_req)
-        think_now = take_cur(wl.phase_think)
-        recycle = live & (take_cur(wl.arrival_mode) == ARRIVAL_CLOSED)
+        with jax.named_scope(spans.TICK_ARRIVALS):
+            row0 = jax.lax.axis_index(AXIS_SERVERS).astype(jnp.int32) * s_loc
+            t = state.t
+            t_sec = t.astype(jnp.float32) * cfg.dt
+            started = (t >= wl.phase_start) & phase_real
+            phase_live = started & (t < wl.phase_end)
+            live = phase_live.any(axis=1)
+            cur = jnp.maximum(jnp.max(jnp.where(started, phase_idx, -1),
+                                      axis=1), 0)
+            take_cur = lambda a: jnp.take_along_axis(a, cur[:, None], axis=1)[:, 0]
+            req_now = take_cur(wl.phase_req)
+            think_now = take_cur(wl.phase_think)
+            recycle = live & (take_cur(wl.arrival_mode) == ARRIVAL_CLOSED)
 
-        # -- 1. arrivals: full-[S] accounting, slab-local ring writes -------
-        slot = jnp.mod(t, h_)
-        inject = ((t == wl.phase_start) & phase_real & fresh_start
-                  & (wl.arrival_mode == ARRIVAL_CLOSED)).any(axis=1)
-        if has_interval:
-            gap = jnp.mod(t - wl.phase_start,
-                          jnp.maximum(wl.arrival_every, 1))
-            inject = inject | (phase_live & (gap == 0)
-                               & (wl.arrival_mode == ARRIVAL_INTERVAL)
-                               ).any(axis=1)
-        arrivals = gat(state.wheel[:, :, slot]) + jnp.where(
-            inject[None, :], wl.procs, 0)                          # [S, J]
-        key_carry = state.key
-        if has_poisson:
-            key_carry, kp = jax.random.split(state.key)
-            lam = jnp.where(
-                phase_live & (wl.arrival_mode == ARRIVAL_POISSON),
-                wl.arrival_rate, 0.0).sum(axis=1)
-            arrivals = arrivals + jax.random.poisson(
-                kp, lam[None, :] * wl.procs).astype(jnp.int32)
-        wheel = state.wheel.at[:, :, slot].set(0)                  # local
-        qcount = gat(state.qcount)
-        head = gat(state.head)
-        known = gat(state.known)
-        # _push_arrivals on the full control plane; the arr_time write (the
-        # O(S·J·CAP) part) is masked down to this device's slab rows.
-        space = jnp.maximum(cap - qcount, 0)
-        accepted = jnp.minimum(arrivals, space)
-        idx = jnp.arange(cap, dtype=jnp.int32)[None, None, :]
-        tail = rows(head + qcount)[..., None]
-        pos = (idx - tail) % cap
-        mask = pos < rows(accepted)[..., None]
-        arr_time = jnp.where(mask, jnp.float32(t_sec), state.arr_time)
-        qcount = qcount + accepted
-        known = known | (accepted > 0)
-        issued = state.issued + accepted.sum(axis=0).astype(jnp.int32)
-        dropped = state.dropped + (arrivals - accepted).sum().astype(jnp.int32)
+            # -- 1. arrivals: full-[S] accounting, slab-local ring writes -------
+            slot = jnp.mod(t, h_)
+            inject = ((t == wl.phase_start) & phase_real & fresh_start
+                      & (wl.arrival_mode == ARRIVAL_CLOSED)).any(axis=1)
+            if has_interval:
+                gap = jnp.mod(t - wl.phase_start,
+                              jnp.maximum(wl.arrival_every, 1))
+                inject = inject | (phase_live & (gap == 0)
+                                   & (wl.arrival_mode == ARRIVAL_INTERVAL)
+                                   ).any(axis=1)
+            arrivals = gat(state.wheel[:, :, slot]) + jnp.where(
+                inject[None, :], wl.procs, 0)                          # [S, J]
+            key_carry = state.key
+            if has_poisson:
+                key_carry, kp = jax.random.split(state.key)
+                lam = jnp.where(
+                    phase_live & (wl.arrival_mode == ARRIVAL_POISSON),
+                    wl.arrival_rate, 0.0).sum(axis=1)
+                arrivals = arrivals + jax.random.poisson(
+                    kp, lam[None, :] * wl.procs).astype(jnp.int32)
+            wheel = state.wheel.at[:, :, slot].set(0)                  # local
+            qcount = gat(state.qcount)
+            head = gat(state.head)
+            known = gat(state.known)
+            # _push_arrivals on the full control plane; the arr_time write (the
+            # O(S·J·CAP) part) is masked down to this device's slab rows.
+            space = jnp.maximum(cap - qcount, 0)
+            accepted = jnp.minimum(arrivals, space)
+            idx = jnp.arange(cap, dtype=jnp.int32)[None, None, :]
+            tail = rows(head + qcount)[..., None]
+            pos = (idx - tail) % cap
+            mask = pos < rows(accepted)[..., None]
+            arr_time = jnp.where(mask, jnp.float32(t_sec), state.arr_time)
+            qcount = qcount + accepted
+            known = known | (accepted > 0)
+            issued = state.issued + accepted.sum(axis=0).astype(jnp.int32)
+            dropped = state.dropped + (arrivals - accepted).sum().astype(jnp.int32)
 
-        # -- 2. scheduler bookkeeping on the gathered control plane ---------
-        seg = gat(state.seg)
-        aux = jax.tree.map(gat, state.aux)
-        aux = sched.pre_tick(cfg, p, aux, qcount, t)
-        shares = sched.tick_shares(cfg, table, TickView(
-            qcount=qcount, known=known, seg=seg,
-            synced=state.synced, live=live))
+        with jax.named_scope(spans.TICK_SCHED):
+            # -- 2. scheduler bookkeeping on the gathered control plane ---------
+            ctrl = sched.ctrl_overhead_s(p)
+            seg = gat(state.seg)
+            aux = jax.tree.map(gat, state.aux)
+            aux = sched.pre_tick(cfg, p, aux, qcount, t)
+            shares = sched.tick_shares(cfg, table, TickView(
+                qcount=qcount, known=known, seg=seg,
+                synced=state.synced, live=live))
 
-        # -- 3. workers -----------------------------------------------------
-        key, sub = jax.random.split(key_carry)
-        bytes_job = jnp.zeros((j_,), jnp.float32)
-        pops_job = jnp.zeros((j_,), jnp.int32)
-        idle_ticks = jnp.zeros((), jnp.int32)
-        free_at = gat(state.free_at)
-        # The only ring data the worker phase can touch: worker w pops at
-        # ring offset pops[s, j] <= w < W, so a W-wide window starting at
-        # head covers every head_time read this tick.  Gathering the window
-        # ([S, J, W]) instead of the ring ([S, J, CAP]) is what keeps the
-        # heavy slab local.
-        koff = jnp.arange(w_, dtype=jnp.int32)[None, None, :]
-        ring_idx = jnp.mod(rows(head)[..., None] + koff, cap)
-        window = gat(jnp.take_along_axis(arr_time, ring_idx, axis=-1))
+        with jax.named_scope(spans.TICK_WORKERS):
+            # -- 3. workers -----------------------------------------------------
+            key, sub = jax.random.split(key_carry)
+            bytes_job = jnp.zeros((j_,), jnp.float32)
+            pops_job = jnp.zeros((j_,), jnp.int32)
+            idle_ticks = jnp.zeros((), jnp.int32)
+            free_at = gat(state.free_at)
+            # The only ring data the worker phase can touch: worker w pops at
+            # ring offset pops[s, j] <= w < W, so a W-wide window starting at
+            # head covers every head_time read this tick.  Gathering the window
+            # ([S, J, W]) instead of the ring ([S, J, CAP]) is what keeps the
+            # heavy slab local.
+            koff = jnp.arange(w_, dtype=jnp.int32)[None, None, :]
+            ring_idx = jnp.mod(rows(head)[..., None] + koff, cap)
+            window = gat(jnp.take_along_axis(arr_time, ring_idx, axis=-1))
 
-        def worker_body(carry, w):
-            (qcount, head, pops, wheel, free_at, aux, bytes_job, pops_job,
+            def worker_body(carry, w):
+                (qcount, head, pops, wheel, free_at, aux, bytes_job, pops_job,
+                 idle_ticks) = carry
+                kw = jax.random.fold_in(sub, w)
+                free = free_at[:, w] < t_sec + cfg.dt
+                demand = qcount > 0
+                head_time = jnp.where(
+                    demand,
+                    jnp.take_along_axis(
+                        window, jnp.minimum(pops, w_ - 1)[..., None],
+                        axis=-1)[..., 0],
+                    jnp.inf)
+                j_sel = sched.select(cfg, p, shares, head_time, demand, aux,
+                                     req_now, kw)
+                valid = free & (j_sel >= 0)
+                j_safe = jnp.maximum(j_sel, 0)
+                onehot = (jax.nn.one_hot(j_safe, j_, dtype=jnp.int32)
+                          * valid[:, None].astype(jnp.int32))
+                qcount = qcount - onehot
+                head = jnp.mod(head + onehot, cap)
+                pops = pops + onehot
+                rb = req_now[j_safe]
+                service = rb / worker_bw + wl.overhead_s[j_safe] + ctrl
+                start_t = jnp.maximum(free_at[:, w], t_sec)
+                new_free = jnp.where(valid, start_t + service, free_at[:, w])
+                free_at = free_at.at[:, w].set(new_free)
+                job_live = recycle[j_safe]
+                off = (jnp.ceil((new_free - t_sec) / cfg.dt).astype(jnp.int32)
+                       + think_now[j_safe])
+                off = jnp.clip(off, 1, h_ - 1)
+                slot2 = jnp.mod(t + off, h_)
+                add = (valid & job_live).astype(jnp.int32)
+                wheel = wheel.at[srv_loc, rows(j_safe), rows(slot2)].add(rows(add))
+                add_b = jnp.where(valid, rb, 0.0)
+                bytes_job = bytes_job.at[j_safe].add(add_b)
+                pops_job = pops_job.at[j_safe].add(valid.astype(jnp.int32))
+                aux = sched.charge(cfg, p, aux, srv_idx, j_safe, add_b)
+                idle_ticks = idle_ticks + (
+                    free & ~valid & demand.any(axis=1)).sum().astype(jnp.int32)
+                return (qcount, head, pops, wheel, free_at, aux, bytes_job,
+                        pops_job, idle_ticks), None
+
+            carry = (qcount, head, jnp.zeros((s_, j_), jnp.int32), wheel,
+                     free_at, aux, bytes_job, pops_job, idle_ticks)
+            carry, _ = jax.lax.scan(worker_body, carry,
+                                    jnp.arange(w_, dtype=jnp.int32))
+            (qcount, head, _pops, wheel, free_at, aux, bytes_job, pops_job,
              idle_ticks) = carry
-            kw = jax.random.fold_in(sub, w)
-            free = free_at[:, w] < t_sec + cfg.dt
-            demand = qcount > 0
-            head_time = jnp.where(
-                demand,
-                jnp.take_along_axis(
-                    window, jnp.minimum(pops, w_ - 1)[..., None],
-                    axis=-1)[..., 0],
-                jnp.inf)
-            j_sel = sched.select(cfg, p, shares, head_time, demand, aux,
-                                 req_now, kw)
-            valid = free & (j_sel >= 0)
-            j_safe = jnp.maximum(j_sel, 0)
-            onehot = jax.nn.one_hot(j_safe, j_, dtype=jnp.int32) * valid[:, None].astype(jnp.int32)
-            qcount = qcount - onehot
-            head = jnp.mod(head + onehot, cap)
-            pops = pops + onehot
-            rb = req_now[j_safe]
-            service = rb / worker_bw + wl.overhead_s[j_safe] + ctrl
-            start_t = jnp.maximum(free_at[:, w], t_sec)
-            new_free = jnp.where(valid, start_t + service, free_at[:, w])
-            free_at = free_at.at[:, w].set(new_free)
-            job_live = recycle[j_safe]
-            off = jnp.ceil((new_free - t_sec) / cfg.dt).astype(jnp.int32) + think_now[j_safe]
-            off = jnp.clip(off, 1, h_ - 1)
-            slot2 = jnp.mod(t + off, h_)
-            add = (valid & job_live).astype(jnp.int32)
-            wheel = wheel.at[srv_loc, rows(j_safe), rows(slot2)].add(rows(add))
-            add_b = jnp.where(valid, rb, 0.0)
-            bytes_job = bytes_job.at[j_safe].add(add_b)
-            pops_job = pops_job.at[j_safe].add(valid.astype(jnp.int32))
-            aux = sched.charge(cfg, p, aux, srv_idx, j_safe, add_b)
-            idle_ticks = idle_ticks + (free & ~valid & demand.any(axis=1)).sum().astype(jnp.int32)
-            return (qcount, head, pops, wheel, free_at, aux, bytes_job,
-                    pops_job, idle_ticks), None
-
-        carry = (qcount, head, jnp.zeros((s_, j_), jnp.int32), wheel,
-                 free_at, aux, bytes_job, pops_job, idle_ticks)
-        carry, _ = jax.lax.scan(worker_body, carry,
-                                jnp.arange(w_, dtype=jnp.int32))
-        (qcount, head, _pops, wheel, free_at, aux, bytes_job, pops_job,
-         idle_ticks) = carry
 
         # -- 4. finish: replicated fold + λ-sync, slab slice-back -----------
-        b = jnp.minimum(t // cfg.bin_ticks, n_bins - 1)
         new_t = t + 1
         synced = state.synced
         if sched.uses_segments and cfg.sync_ticks > 0:
@@ -722,21 +738,56 @@ def make_tick(cfg: EngineConfig, wl: Workload, table: JobTable, n_bins: int,
                 return (sync_segments(cfg.policy, table, support,
                                       n_iters=cfg.sinkhorn_iters),
                         support.any(axis=0))
-            seg, synced = jax.lax.cond(
-                jnp.mod(new_t, cfg.sync_ticks) == 0, do_sync,
-                lambda a: a, (seg, synced))
-        state = state._replace(
-            t=new_t, key=key, qcount=rows(qcount), head=rows(head),
-            arr_time=arr_time, wheel=wheel, free_at=rows(free_at),
-            known=rows(known), seg=rows(seg), synced=synced,
-            aux=jax.tree.map(rows, aux),
-            bytes_bin=state.bytes_bin.at[:, b].add(bytes_job),
-            issued=issued, completed=state.completed + pops_job,
-            idle_worker_ticks=state.idle_worker_ticks + idle_ticks,
-            dropped=dropped)
+            with jax.named_scope(spans.TICK_SYNC):
+                seg, synced = jax.lax.cond(
+                    jnp.mod(new_t, cfg.sync_ticks) == 0, do_sync,
+                    lambda a: a, (seg, synced))
+        with jax.named_scope(spans.TICK_FINISH):
+            b = jnp.minimum(t // cfg.bin_ticks, n_bins - 1)
+            state = state._replace(
+                t=new_t, key=key, qcount=rows(qcount), head=rows(head),
+                arr_time=arr_time, wheel=wheel, free_at=rows(free_at),
+                known=rows(known), seg=rows(seg), synced=synced,
+                aux=jax.tree.map(rows, aux),
+                bytes_bin=state.bytes_bin.at[:, b].add(bytes_job),
+                issued=issued, completed=state.completed + pops_job,
+                idle_worker_ticks=state.idle_worker_ticks + idle_ticks,
+                dropped=dropped)
         return state, None
 
     return tick_sharded
+
+
+def _call_marks() -> tuple:
+    """What the per-call counters count from: jit traces so far and the
+    compile cache's ``(requests, hits)``."""
+    return (len(TRACE_LOG),) + cache_events()
+
+
+def _call_counters(marks: tuple, ticks: int, lanes: int,
+                   tick_impl: str) -> dict:
+    """One engine call's counters (see ``RunResult.counters``)."""
+    traces, requests, hits = marks
+    now_requests, now_hits = cache_events()
+    return {
+        "lanes": lanes,
+        # One fused invocation per tick serves every lane (vmap batches it).
+        "kernel_invocations": ticks if tick_impl == "pallas" else 0,
+        "jit_traces": len(TRACE_LOG) - traces,
+        "compile_cache_requests": now_requests - requests,
+        "compile_cache_hits": now_hits - hits,
+    }
+
+
+def _execute(fn, args):
+    """Call the jitted program and wait for the device, under the dispatch
+    and device-wait spans.  The wait adds none: the first host copy of the
+    result would block there anyway."""
+    with jax.profiler.TraceAnnotation(spans.ENGINE_DISPATCH):
+        state = fn(*args)
+    with jax.profiler.TraceAnnotation(spans.ENGINE_DEVICE_WAIT):
+        jax.block_until_ready(state)
+    return state
 
 
 def _run_program(cfg: EngineConfig, wl: Workload, table: JobTable,
@@ -795,20 +846,26 @@ def run(cfg: EngineConfig, wl: Workload, table: JobTable, sim_seconds: float):
     path.  A sweep axis in ``mesh_shape`` is idle here (one run has no grid
     axis) — lanes replicate over it.
     """
-    fn, args, ticks = _run_program(cfg, wl, table, sim_seconds)
-    state = fn(*args)
+    marks = _call_marks()
+    with jax.profiler.TraceAnnotation(spans.ENGINE_PREPARE):
+        fn, args, ticks = _run_program(cfg, wl, table, sim_seconds)
+        tick_impl = resolve_tick_impl(cfg, get_scheduler(cfg.scheduler))
+    state = _execute(fn, args)
     bin_s = cfg.bin_ticks * cfg.dt
-    return {
-        "state": state,
-        "gbps": np.asarray(state.bytes_bin) / bin_s / 1e9,
-        "bin_s": bin_s,
-        "issued": np.asarray(state.issued),
-        "completed": np.asarray(state.completed),
-        "dropped": int(state.dropped),
-        "idle_worker_ticks": int(state.idle_worker_ticks),
-        "ticks": ticks,
-        "tick_impl": resolve_tick_impl(cfg, get_scheduler(cfg.scheduler)),
-    }
+    with jax.profiler.TraceAnnotation(spans.ENGINE_FETCH):
+        out = {
+            "state": state,
+            "gbps": np.asarray(state.bytes_bin) / bin_s / 1e9,
+            "bin_s": bin_s,
+            "issued": np.asarray(state.issued),
+            "completed": np.asarray(state.completed),
+            "dropped": int(state.dropped),
+            "idle_worker_ticks": int(state.idle_worker_ticks),
+            "ticks": ticks,
+            "tick_impl": tick_impl,
+        }
+    out["counters"] = _call_counters(marks, ticks, 1, tick_impl)
+    return out
 
 
 def run_batch(cfg: EngineConfig, wl: Workload, table: JobTable,
@@ -838,23 +895,50 @@ def run_batch(cfg: EngineConfig, wl: Workload, table: JobTable,
     stays bit-identical to its sequential :func:`run`.
     """
     seeds = [int(normalize_seed(s)) for s in seeds]
+    sched = get_scheduler(cfg.scheduler)
+    points = None if params_points is None else list(params_points)
+    for p in points or ():
+        if type(p) is not sched.params_cls:
+            raise TypeError(
+                f"params_points entries must be {sched.params_cls.__name__} "
+                f"for scheduler {cfg.scheduler!r}, got {type(p).__name__}")
+    marks = _call_marks()
+    with jax.profiler.TraceAnnotation(spans.ENGINE_PREPARE):
+        fn, args, ticks = _batch_program(cfg, wl, table, sim_seconds, seeds,
+                                         points)
+        tick_impl = resolve_tick_impl(cfg, sched)
+    state = _execute(fn, args)
+    bin_s = cfg.bin_ticks * cfg.dt
+    with jax.profiler.TraceAnnotation(spans.ENGINE_FETCH):
+        out = {
+            "state": state,
+            "seeds": np.asarray(seeds, dtype=np.uint32),
+            "gbps": np.asarray(state.bytes_bin) / bin_s / 1e9,   # [(P,) K, J, NB]
+            "bin_s": bin_s,
+            "issued": np.asarray(state.issued),                  # [(P,) K, J]
+            "completed": np.asarray(state.completed),            # [(P,) K, J]
+            "dropped": np.asarray(state.dropped),                # [(P,) K]
+            "idle_worker_ticks": np.asarray(state.idle_worker_ticks),  # [(P,) K]
+            "ticks": ticks,
+            "tick_impl": tick_impl,
+        }
+    lanes = len(seeds) * (1 if points is None else len(points))
+    out["counters"] = _call_counters(marks, ticks, lanes, tick_impl)
+    return out
+
+
+def _batch_program(cfg: EngineConfig, wl: Workload, table: JobTable,
+                   sim_seconds: float, seeds: Sequence[int],
+                   points: Optional[list]):
+    """:func:`run_batch`'s jitted program and its arguments, like
+    :func:`_run_program`: ``(fn, args, ticks)``."""
     ticks = int(round(sim_seconds / cfg.dt))
     n_bins = max(1, (ticks + cfg.bin_ticks - 1) // cfg.bin_ticks)
     shard = resolve_shard(cfg)
     tick = make_tick(cfg, wl, table, n_bins, shard=shard)
     base = init_state(cfg, n_bins)
     sched = get_scheduler(cfg.scheduler)
-    if params_points is None:
-        params = sched.params(cfg)
-        points = None
-    else:
-        points = list(params_points)
-        for p in points:
-            if type(p) is not sched.params_cls:
-                raise TypeError(
-                    f"params_points entries must be {sched.params_cls.__name__} "
-                    f"for scheduler {cfg.scheduler!r}, got {type(p).__name__}")
-        params = stack_params(points)
+    params = sched.params(cfg) if points is None else stack_params(points)
     seed_arr = jnp.asarray(seeds, dtype=jnp.uint32)
     # The explicit index supplies the mapped-axis size even for schemas with
     # no numeric leaves (themis/fifo), where ``params`` alone carries no
@@ -902,17 +986,4 @@ def run_batch(cfg: EngineConfig, wl: Workload, table: JobTable,
             out_specs=state_specs(base, shard, lead=lead),
             check_vma=False))
 
-    state = _run_all(params, seed_arr, point_idx, base)
-    bin_s = cfg.bin_ticks * cfg.dt
-    return {
-        "state": state,
-        "seeds": np.asarray(seeds, dtype=np.uint32),
-        "gbps": np.asarray(state.bytes_bin) / bin_s / 1e9,   # [(P,) K, J, NB]
-        "bin_s": bin_s,
-        "issued": np.asarray(state.issued),                  # [(P,) K, J]
-        "completed": np.asarray(state.completed),            # [(P,) K, J]
-        "dropped": np.asarray(state.dropped),                # [(P,) K]
-        "idle_worker_ticks": np.asarray(state.idle_worker_ticks),  # [(P,) K]
-        "ticks": ticks,
-        "tick_impl": resolve_tick_impl(cfg, sched),
-    }
+    return _run_all, (params, seed_arr, point_idx, base), ticks

@@ -61,14 +61,16 @@ def _require(cond, msg: str) -> None:
 def _abstract_values(p) -> bool:
     """True when any field came from pytree plumbing rather than a concrete
     construction: a JAX tracer (jit argument / vmap lane), a non-scalar
-    array (a stacked sweep grid), or the bare ``object()`` sentinels JAX
+    array (a stacked sweep grid), the ``ArgInfo`` leaves ``jit(...).lower``
+    rebuilds the arguments from, or the bare ``object()`` sentinels JAX
     threads through ``unflatten`` during tree transposition.  Validation
     skips those — they were validated when their concrete grid points were
     built — but still runs (and raises eagerly, e.g. on a string) for every
     genuinely concrete value."""
     for f in dataclasses.fields(p):
         v = getattr(p, f.name)
-        if isinstance(v, jax.core.Tracer) or type(v) is object:
+        if (isinstance(v, (jax.core.Tracer, jax.stages.ArgInfo))
+                or type(v) is object):
             return True
         if getattr(v, "ndim", 0) != 0:
             return True

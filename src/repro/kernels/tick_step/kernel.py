@@ -32,6 +32,9 @@ from ..prefix import first_index
 from ..token_select.ref import weighted_draw
 from .ref import MODES
 
+#: The kernel's name in lowered programs and in profiler traces.
+KERNEL_NAME = "tick_step_pallas"
+
 
 def _tick_step_kernel(shares_ref, qcount_ref, window_ref, free_ref, u_ref,
                       sel_ref, valid_ref, dany_ref, qout_ref, pops_ref, *,
@@ -130,6 +133,7 @@ def tick_step_pallas(shares: jnp.ndarray, qcount: jnp.ndarray,
             jax.ShapeDtypeStruct((sp, jp), jnp.int32),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(shares_p, qcount_p, window_p, free_p, u_p)
     return (sel[:s], valid[:s] > 0, dany[:s] > 0, qout[:s, :j],
             pops[:s, :j])

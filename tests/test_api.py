@@ -144,7 +144,9 @@ class TestRunResult:
         import json
         c = res.counters()
         assert set(c) == {"scheduler", "policy", "params_hash", "dropped",
-                          "idle_worker_ticks", "tick_impl"}
+                          "idle_worker_ticks", "tick_impl", "ticks", "lanes",
+                          "kernel_invocations", "jit_traces",
+                          "compile_cache_requests", "compile_cache_hits"}
         assert c["tick_impl"] in ("ref", "pallas")
         json.dumps(c)
 
