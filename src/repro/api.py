@@ -101,9 +101,9 @@ class RunResult:
     #: see :func:`repro.core.engine.resolve_tick_impl`).
     tick_impl: Optional[str] = None
     #: Counters of the engine call that produced this result (``lanes``,
-    #: ``kernel_invocations``, ``jit_traces``, ``compile_cache_requests``,
-    #: ``compile_cache_hits``); empty where no engine call ran (a result
-    #: read back from a workspace).
+    #: ``kernel_invocations``, ``kernel_grid_steps``, ``jit_traces``,
+    #: ``compile_cache_requests``, ``compile_cache_hits``); empty where no
+    #: engine call ran (a result read back from a workspace).
     call_counters: Mapping = dataclasses.field(default_factory=dict)
 
     # -- legacy dict-style access (repro.core.metrics helpers) ---------------
@@ -169,7 +169,8 @@ class RunResult:
         """The attribution block BENCH_*.json artifacts embed per run: what
         ran, and the counters of the engine call (``ticks`` simulated,
         ``lanes`` advanced together, ``kernel_invocations`` of the fused
-        tick step, ``jit_traces`` of the engine scan, and the persistent
+        tick step and the ``kernel_grid_steps`` of each (0 on the scan
+        path), ``jit_traces`` of the engine scan, and the persistent
         compile cache's ``compile_cache_requests``/``compile_cache_hits``)."""
         return {
             "scheduler": self.scheduler,

@@ -65,7 +65,7 @@ from .shard import (AXIS_SERVERS, AXIS_SWEEP, ShardSpec, resolve_shard,
                     state_specs)
 from . import spans
 from repro.compile_cache import cache_events
-from repro.kernels.tick_step import tick_step
+from repro.kernels.tick_step import tick_step, tick_step_grid
 
 #: One entry is appended each time an engine scan is traced for XLA.
 #: ``run``/``run_batch`` build a fresh jit per call, so every entry
@@ -464,14 +464,18 @@ def make_tick(cfg: EngineConfig, wl: Workload, table: JobTable, n_bins: int,
                 # worker only ever reads/writes its own free_at column and
                 # arr_time is read-only across the phase, so free/window can be
                 # materialized up front; a worker pops at ring offset pops[s,j]
-                # < W, which is why a [S, J, W] window covers every draw.
+                # < W, which is why a [S, J, W] window covers every draw.  Only
+                # fifo reads the window: themis draws from the shares alone.
                 free = state.free_at < t_sec + cfg.dt                  # [S, W]
                 u_all = jnp.stack(
                     [jax.random.uniform(jax.random.fold_in(sub, w), (s_,))
                      for w in range(w_)], axis=1)                      # [S, W]
-                koff = jnp.arange(w_, dtype=jnp.int32)[None, None, :]
-                ring_idx = jnp.mod(state.head[..., None] + koff, cap)
-                window = jnp.take_along_axis(state.arr_time, ring_idx, axis=-1)
+                window = None
+                if sched.kernel_select_mode == "fifo":
+                    koff = jnp.arange(w_, dtype=jnp.int32)[None, None, :]
+                    ring_idx = jnp.mod(state.head[..., None] + koff, cap)
+                    window = jnp.take_along_axis(state.arr_time, ring_idx,
+                                                 axis=-1)
                 sel, valid, demand_any, qcount, pops_sj = tick_step(
                     shares, state.qcount, window, free, u_all,
                     mode=sched.kernel_select_mode, impl="pallas")
@@ -764,15 +768,29 @@ def _call_marks() -> tuple:
     return (len(TRACE_LOG),) + cache_events()
 
 
-def _call_counters(marks: tuple, ticks: int, lanes: int,
-                   tick_impl: str) -> dict:
+def _kernel_grid_steps(cfg: EngineConfig, sched: Scheduler, tick_impl: str,
+                       lanes: int) -> int:
+    """Grid steps of one fused kernel invocation, 0 on the scan path: the
+    vmap lanes one device runs fold into the kernel's rows, which
+    :func:`~repro.kernels.tick_step.kernel.tick_step_grid` blocks."""
+    if tick_impl != "pallas":
+        return 0
+    shard = resolve_shard(cfg)
+    per_device = max(1, lanes // (shard.n_sweep if shard else 1))
+    return tick_step_grid(per_device * cfg.n_servers, cfg.max_jobs,
+                          cfg.n_workers, sched.kernel_select_mode)[1]
+
+
+def _call_counters(marks: tuple, ticks: int, lanes: int, tick_impl: str,
+                   grid_steps: int) -> dict:
     """One engine call's counters (see ``RunResult.counters``)."""
     traces, requests, hits = marks
     now_requests, now_hits = cache_events()
     return {
         "lanes": lanes,
-        # One fused invocation per tick serves every lane (vmap batches it).
+        # One fused invocation per tick serves every lane (vmap folds them).
         "kernel_invocations": ticks if tick_impl == "pallas" else 0,
+        "kernel_grid_steps": grid_steps,
         "jit_traces": len(TRACE_LOG) - traces,
         "compile_cache_requests": now_requests - requests,
         "compile_cache_hits": now_hits - hits,
@@ -849,7 +867,8 @@ def run(cfg: EngineConfig, wl: Workload, table: JobTable, sim_seconds: float):
     marks = _call_marks()
     with jax.profiler.TraceAnnotation(spans.ENGINE_PREPARE):
         fn, args, ticks = _run_program(cfg, wl, table, sim_seconds)
-        tick_impl = resolve_tick_impl(cfg, get_scheduler(cfg.scheduler))
+        sched = get_scheduler(cfg.scheduler)
+        tick_impl = resolve_tick_impl(cfg, sched)
     state = _execute(fn, args)
     bin_s = cfg.bin_ticks * cfg.dt
     with jax.profiler.TraceAnnotation(spans.ENGINE_FETCH):
@@ -864,7 +883,9 @@ def run(cfg: EngineConfig, wl: Workload, table: JobTable, sim_seconds: float):
             "ticks": ticks,
             "tick_impl": tick_impl,
         }
-    out["counters"] = _call_counters(marks, ticks, 1, tick_impl)
+    out["counters"] = _call_counters(
+        marks, ticks, 1, tick_impl,
+        _kernel_grid_steps(cfg, sched, tick_impl, 1))
     return out
 
 
@@ -923,7 +944,9 @@ def run_batch(cfg: EngineConfig, wl: Workload, table: JobTable,
             "tick_impl": tick_impl,
         }
     lanes = len(seeds) * (1 if points is None else len(points))
-    out["counters"] = _call_counters(marks, ticks, lanes, tick_impl)
+    out["counters"] = _call_counters(
+        marks, ticks, lanes, tick_impl,
+        _kernel_grid_steps(cfg, sched, tick_impl, lanes))
     return out
 
 

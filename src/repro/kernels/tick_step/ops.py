@@ -33,7 +33,9 @@ def tick_step(shares, qcount, window, free, u, *, mode: str = "themis",
               impl: str = "auto"):
     """The whole worker phase of one engine tick, fused.
 
-    shares, qcount: [S, J]; window: [S, J, W]; free, u: [S, W].
+    shares, qcount: [S, J]; window: [S, J, W] in fifo mode (themis mode
+    reads none: pass None); free, u: [S, W].  Under ``jax.vmap`` the Pallas
+    path folds the lanes into the rows of one kernel invocation.
     Returns ``(sel i32[S,W], valid bool[S,W], demand_any bool[S,W],
     qcount_out i32[S,J], pops i32[S,J])`` — semantics in ref.py.
     """

@@ -11,7 +11,9 @@ Inputs are plain arrays so both planes can call it:
     shares  f32[S, J]   per-tick share table (themis mode)
     qcount  i32[S, J]   queued requests per (server, job) at tick start
     window  f32[S, J, W] next W ring arrival stamps per (server, job)
-                        (window[s, j, k] = arr_time[s, j, (head + k) % cap])
+                        (window[s, j, k] = arr_time[s, j, (head + k) % cap]);
+                        fifo mode only — themis mode reads none, and the
+                        caller may pass None
     free    bool[S, W]  worker is free this tick
     u       f32[S, W]   per-worker uniform draws (PRNG stream precomputed
                         by the caller — stream identity is the caller's job)
@@ -43,11 +45,20 @@ def _fifo_pick(head_time: jnp.ndarray, demand: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(demand.any(axis=-1), j, -1)
 
 
-def tick_step_ref(shares: jnp.ndarray, qcount: jnp.ndarray,
-                  window: jnp.ndarray, free: jnp.ndarray, u: jnp.ndarray,
-                  mode: str = "themis"):
+def check_mode(mode: str, window) -> None:
+    """Fail loudly on an unknown mode, and on fifo mode without its
+    window."""
     if mode not in MODES:
         raise ValueError(f"unknown tick-step mode {mode!r}; one of {MODES}")
+    if mode == "fifo" and window is None:
+        raise ValueError("fifo mode reads the [S, J, W] ring window; "
+                         "got window=None")
+
+
+def tick_step_ref(shares: jnp.ndarray, qcount: jnp.ndarray,
+                  window, free: jnp.ndarray, u: jnp.ndarray,
+                  mode: str = "themis"):
+    check_mode(mode, window)
     s_, j_ = qcount.shape
     w_ = u.shape[1]
     pops = jnp.zeros_like(qcount)

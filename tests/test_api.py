@@ -145,7 +145,8 @@ class TestRunResult:
         c = res.counters()
         assert set(c) == {"scheduler", "policy", "params_hash", "dropped",
                           "idle_worker_ticks", "tick_impl", "ticks", "lanes",
-                          "kernel_invocations", "jit_traces",
+                          "kernel_invocations", "kernel_grid_steps",
+                          "jit_traces",
                           "compile_cache_requests", "compile_cache_hits"}
         assert c["tick_impl"] in ("ref", "pallas")
         json.dumps(c)

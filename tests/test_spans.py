@@ -122,6 +122,8 @@ def test_counters_count_the_call(impl):
         assert c["tick_impl"] == impl
         assert (c["ticks"], c["lanes"]) == (20, lanes)
         assert c["kernel_invocations"] == (20 if impl == "pallas" else 0)
+        # 2 servers x up to 3 lanes fold into one block of one grid step.
+        assert c["kernel_grid_steps"] == (1 if impl == "pallas" else 0)
         assert c["jit_traces"] == 1
         assert 0 <= c["compile_cache_hits"] <= c["compile_cache_requests"]
     # A lane sliced out of a batch keeps the counters of its call.

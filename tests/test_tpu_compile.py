@@ -5,7 +5,8 @@ XLA on the CPU, so it cannot see what the TPU compiler refuses: primitives
 Mosaic has no lowering for, unaligned slices, VMEM overflow.  These tests
 compile ``tick_step_pallas`` (both modes) and ``token_select_pallas`` with
 ``interpret=False`` for one chip of a described ``v5e:2x2`` topology, at
-paper geometry (S=8, J=16, W=8) and fleet geometry (S=128, J=1024, W=4).
+paper geometry (S=8, J=16, W=8), fleet geometry (S=128, J=1024, W=4) and
+the 128-node cell's (S=128, J=8, W=8: themis in one grid step).
 Nothing runs; a compile that passes is not a chip run.
 
 The topology is described inside a module fixture — never at import, in
@@ -24,7 +25,8 @@ from repro.kernels.tick_step.kernel import tick_step_pallas
 from repro.kernels.tick_step.ref import MODES
 from repro.kernels.token_select.kernel import token_select_pallas
 
-GEOMETRIES = {"paper": (8, 16, 8), "fleet": (128, 1024, 4)}
+GEOMETRIES = {"paper": (8, 16, 8), "fleet": (128, 1024, 4),
+              "fleet128": (128, 8, 8)}
 
 
 @pytest.fixture(scope="module")
@@ -60,9 +62,11 @@ def _compiled_text(fn, args) -> str:
 @pytest.mark.parametrize("mode", MODES)
 def test_tick_step_compiles_for_v5e(one_chip, mode, geometry):
     s, j, w = GEOMETRIES[geometry]
+    window = (_spec((s, j, w), jnp.float32, one_chip) if mode == "fifo"
+              else None)
     args = (_spec((s, j), jnp.float32, one_chip),
             _spec((s, j), jnp.int32, one_chip),
-            _spec((s, j, w), jnp.float32, one_chip),
+            window,
             _spec((s, w), jnp.bool_, one_chip),
             _spec((s, w), jnp.float32, one_chip))
     text = _compiled_text(
