@@ -1,5 +1,5 @@
-"""The control of ``correct``: the reference in bfloat16 in the program's
-place, at a cell's own size, read against the float32 reference.
+"""The control of ``correct``: the cell's reference in bfloat16 in the
+program's place, at a cell's own size, read against the float32 reference.
 
     python3 -m bench.control --workload fig12.themis --seeds 1,2,3
 
@@ -30,16 +30,19 @@ def control_readings(cell: spec.Cell, seeds) -> list[dict]:
     from bench import reference
 
     cfg, tr = cell.config, cell.traffic
+    ref = spec.load_reference(cell)
     jobs = spec.make_jobs(cfg)
     tick_end = reference.tick_end_rounding(cfg["dt"])
-    sim = reference.make_simulator(cfg, tr, jobs, tick_end=tick_end)
-    sim_low = reference.make_simulator(cfg, tr, jobs, jnp.bfloat16, tick_end)
+    sim = ref.make_simulator(cfg, tr, jobs, float_dtype=jnp.float32,
+                             tick_end=tick_end)
+    sim_low = ref.make_simulator(cfg, tr, jobs, float_dtype=jnp.bfloat16,
+                                 tick_end=tick_end)
     out = []
     for seed in seeds:
         lanes = spec.call_seeds(seed, 1, int(cfg["lanes"]))
         t0 = time.perf_counter()
-        exact = reference.run_lanes(sim, tr, lanes)
-        low = reference.run_lanes(sim_low, tr, lanes)
+        exact = ref.run_lanes(sim, tr, lanes)
+        low = ref.run_lanes(sim_low, tr, lanes)
         ok, numbers = check.verdict(check.mismatches(low, exact))
         out.append({"seed": seed, "correct": ok,
                     "seconds": time.perf_counter() - t0,

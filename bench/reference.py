@@ -23,10 +23,34 @@ What it shares with the engine is only what the simulated system is:
   dt`` (:func:`tick_end_rounding`).
 
 Supported: closed-loop single-window jobs, the ``themis`` and ``tbf``
-schedulers, policies whose levels all weigh ``fair``.  Anything else
-raises, so a cell the reference cannot judge never reads as correct.
-``float_dtype`` runs every float of the state in another precision: the
-control, which has to come out as not correct.
+schedulers, policies whose levels all weigh ``fair`` (every such named
+chain, and any ``entity:fair,...`` chain; :func:`policy_levels`).
+Anything else raises, so a cell the reference cannot judge never reads as
+correct.  ``float_dtype`` runs every float of the state in another
+precision: the control, which has to come out as not correct.
+
+**The reference contract.**  Each configuration is judged by one plain
+reference: the file its ``"reference"`` key names (a path under
+``bench/``, e.g. ``bench/references/<config>.py``), or this module where
+it names none.  A deployment whose mechanism this module does not model
+(open-loop arrivals, other weights or schedulers) brings a file of its
+own, and the harness loads it by path (``bench.spec.load_reference``).
+Such a file
+
+* shares no code with the program under test (it imports nothing of
+  ``repro``); it may import this module's helpers (:func:`lower_jobs`,
+  :func:`chain_shares`, :func:`token_draw`, ...);
+* defines ``make_simulator(config, traffic, jobs, float_dtype=...,
+  tick_end=...)``, where ``tick_end`` is :func:`tick_end_rounding` of the
+  backend, and ``run_lanes(sim, traffic, seeds)``, which returns the final
+  state of one experiment call under the engine's field names (scheduler
+  accounts as ``aux.<field>``), as numpy arrays with a leading lane axis,
+  one lane per seed;
+* reads not correct when built with ``float_dtype=jnp.bfloat16``: that is
+  its control, and ``bench/control.py`` runs it.
+
+:func:`tick_end_rounding` and :func:`n_ticks` stay here: they are facts
+about the backend and the configuration that every reference shares.
 """
 from __future__ import annotations
 
@@ -38,12 +62,20 @@ import numpy as np
 
 I32_MAX = int(np.iinfo(np.int32).max)
 
-#: Named policies as chains of sharing entities, each weighing ``fair``.
-POLICY_LEVELS = {
-    "job-fair": ("job",),
-    "user-fair": ("user", "job"),
-    "group-fair": ("group", "user", "job"),
+#: The paper's named policies as ``entity:weight`` chains, coarse to fine.
+NAMED_POLICIES = {
+    "job-fair": "job:fair",
+    "size-fair": "job:size",
+    "priority-fair": "job:priority",
+    "user-fair": "user:fair,job:fair",
+    "group-fair": "group:fair,user:fair,job:fair",
+    "user-then-job-fair": "user:fair,job:fair",
+    "user-then-size-fair": "user:fair,job:size",
+    "group-then-user-fair": "group:fair,user:fair,job:fair",
+    "group-then-size-fair": "group:fair,job:size",
+    "group-user-size-fair": "group:fair,user:fair,job:size",
 }
+ENTITIES = ("group", "user", "job")
 
 SCHEDULERS = ("themis", "tbf")
 AUX_FIELDS = ("budget", "coupons", "served", "bucket", "spare", "borrowed",
@@ -84,6 +116,30 @@ def lower_jobs(config: dict, jobs: list[dict]) -> dict:
         out["group"][j] = int(spec.get("group", 0))
         out["active"][j] = True
     return out
+
+
+def policy_levels(policy: str) -> tuple[str, ...]:
+    """The sharing entities of a policy chain, coarse to fine, ending in
+    ``job``: a named policy or an ``entity[:weight],...`` chain (weight
+    ``fair`` where none is given).  Only ``fair`` weights are modelled; any
+    other raises."""
+    chain = NAMED_POLICIES.get(policy, policy)
+    levels = []
+    for part in chain.split(","):
+        entity, _, weight = part.strip().partition(":")
+        if entity not in ENTITIES:
+            raise ValueError(f"policy {policy!r}: unknown entity {entity!r}")
+        if (weight or "fair") != "fair":
+            raise NotImplementedError(
+                f"policy {policy!r}: the reference models fair weights "
+                f"only, not {weight!r}")
+        levels.append(entity)
+    if levels[-1] != "job":
+        levels.append("job")
+    ranks = [ENTITIES.index(e) for e in levels]
+    if any(b <= a for a, b in zip(ranks, ranks[1:])):
+        raise ValueError(f"policy {policy!r}: levels must run coarse to fine")
+    return tuple(levels)
 
 
 def chain_shares(levels, mask, user, group, fdt):
@@ -260,7 +316,7 @@ def make_simulator(config: dict, traffic: dict, jobs: list[dict],
     srv = jnp.arange(s_, dtype=jnp.int32)
     real = end > start
     if sched == "themis":
-        levels = POLICY_LEVELS[config["policy"]]
+        levels = policy_levels(config["policy"])
         sync_ticks, iters = config["sync_ticks"], config["sinkhorn_iters"]
         shares_of = functools.partial(chain_shares, levels, user=user,
                                       group=group, fdt=fdt)

@@ -13,10 +13,14 @@ One run:
    ``Experiment.run``) back to back until ``--seconds`` have passed, call
    ``i`` on the PRNG seeds drawn from ``--seed`` and ``i``;
 5. with ``--trace 1`` the window's first calls (``TRACED_SECONDS``) run
-   under the profiler and the per-layer metrics are read from their trace
-   (:mod:`bench.trace`, ``bench/metrics``);
+   under the profiler; once the window has closed, the program those calls
+   ran is lowered and compiled again for its op names, and the per-layer
+   metrics are read from the trace (:mod:`bench.trace`), the program's
+   spans and scopes in it (:mod:`bench.spans`) and the calls' counters
+   (``bench/metrics``);
 6. compares a sample of the window's calls, drawn from ``--seed``, with the
-   plain reference (:mod:`bench.reference`, :mod:`bench.check`);
+   configuration's plain reference (:func:`bench.spec.load_reference`,
+   :mod:`bench.check`);
 7. prints counters on earlier lines, the compared numbers beside their
    limits as the last lines of standard error, and one JSON object as the
    last line of standard output.
@@ -28,6 +32,7 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -46,7 +51,7 @@ for _p in (ROOT, ROOT / "src"):
 
 import numpy as np  # noqa: E402
 
-from bench import check, spec, trace  # noqa: E402
+from bench import check, spans, spec, trace  # noqa: E402
 from bench.peaks import device_peaks  # noqa: E402
 
 
@@ -72,22 +77,37 @@ class CacheCounter:
         return self.requests - self.hits
 
 
+#: ``EngineConfig`` fields a configuration may not set: the traffic mix
+#: names the scheduler and its parameters, ``--seed`` gives the PRNG seeds,
+#: and a cell measures the worker-phase path ``auto`` gives users.
+NOT_FROM_CONFIG = ("scheduler", "scheduler_params", "seed", "tick_impl")
+
+
+def engine_options(config: dict) -> dict:
+    """Every key of the configuration that names an ``EngineConfig`` field
+    (lists as tuples); the harness's own keys and the descriptive ones are
+    not engine options."""
+    from repro.core.engine import EngineConfig
+
+    fields = {f.name for f in dataclasses.fields(EngineConfig)}
+    owned = sorted(set(NOT_FROM_CONFIG) & set(config))
+    if owned:
+        raise ValueError(f"a configuration may not set {owned}")
+    return {k: tuple(v) if isinstance(v, list) else v
+            for k, v in config.items() if k in fields}
+
+
 def build_experiment(cell: spec.Cell):
-    """The cell's ``Experiment``: the configuration's geometry and job
+    """The cell's ``Experiment``: the configuration's engine options and job
     population, served by the traffic mix's scheduler."""
     from repro.api import Experiment
     from repro.core.scheduler import get_scheduler
 
-    c, tr = cell.config, cell.traffic
+    tr = cell.traffic
     params = get_scheduler(tr["scheduler"]).params_cls(**tr.get("params", {}))
-    exp = Experiment(
-        policy=c["policy"], scheduler=tr["scheduler"], params=params,
-        n_servers=c["n_servers"], n_workers=c["n_workers"],
-        server_bw=c["server_bw"], max_jobs=c["max_jobs"], dt=c["dt"],
-        wheel=c["wheel"], ring_cap=c["ring_cap"], bin_ticks=c["bin_ticks"],
-        sync_ticks=c["sync_ticks"], sinkhorn_iters=c["sinkhorn_iters"],
-        fabric_exponent=c["fabric_exponent"])
-    return exp.add_jobs(spec.make_jobs(c))
+    exp = Experiment(scheduler=tr["scheduler"], params=params,
+                     **engine_options(cell.config))
+    return exp.add_jobs(spec.make_jobs(cell.config))
 
 
 def experiment_call(exp, config: dict, seeds: tuple):
@@ -101,11 +121,26 @@ def experiment_call(exp, config: dict, seeds: tuple):
     return exp.run(config["sim_seconds"])
 
 
+def lower_call(exp, config: dict, seeds: tuple):
+    """The program one experiment call on ``seeds`` runs, lowered and not
+    run: the same entry, geometry and lane count."""
+    from repro.core import engine
+
+    if config["entry"] == "run_batch":
+        fn, args, _ = engine._batch_program(
+            *exp.build(), config["sim_seconds"],
+            [int(engine.normalize_seed(s)) for s in seeds], None)
+        return fn.lower(*args)
+    exp.seed = seeds[0]
+    return engine.lower_run(*exp.build(), config["sim_seconds"])
+
+
 def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
              *, compile_cache: bool = True, log=print) -> dict:
     """Set up, run the window, read the trace, check; returns the result
     object (without printing it)."""
     import jax
+    import jax.numpy as jnp
 
     cfg = cell.config
     if compile_cache:
@@ -156,7 +191,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         jax.profiler.start_trace(tracedir, profiler_options=opts)
         with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
             te = calls_until(t0, min(seconds, TRACED_SECONDS))
+        t_stop = time.perf_counter()
         jax.profiler.stop_trace()
+        stop_s = time.perf_counter() - t_stop
         n_traced = len(calls)
     if not traced or te - t0 < seconds:
         te = calls_until(t0, seconds)
@@ -164,7 +201,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     misses = counter.misses
     monitoring.unregister_event_listener(counter)
     trace_entries = (len(engine.TRACE_LOG) - n_trace0) / len(calls)
-    peak = (device.memory_stats() or {}).get("peak_bytes_in_use")
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices[:cell.chips]]
+    peak = max((p for p in peaks if p is not None), default=None)
 
     n_calls = len(calls)
     dropped = sum(int(np.asarray(r.dropped).sum()) for _, r in calls)
@@ -183,10 +222,23 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
                          "memory_peak_bytes": peak}}
     ticks = reference.n_ticks(cfg)
     if traced:
-        red = trace.reduce_dir(tracedir)
+        # A TPU trace names each op by its instruction only; the scopes
+        # come from the compiled text of the program the traced calls ran,
+        # compiled again here (the persistent cache has it), after the
+        # window and the memory peak.
+        t_read = [time.perf_counter()]
+        names = spans.op_names(
+            lower_call(exp, cfg, calls[0][0]).compile().as_text())
+        t_read.append(time.perf_counter())
+        events = spans.load(trace.trace_file(tracedir), names)
         shutil.rmtree(tracedir, ignore_errors=True)
-        ctx = dict(reduction=red, cell=cell, ticks=ticks, lanes=lanes,
-                   device_kind=device.device_kind)
+        t_read.append(time.perf_counter())
+        red, sred = trace.reduce_events(events), spans.reduce_spans(events)
+        t_read.append(time.perf_counter())
+        del events
+        counters = [r.counters() for _, r in calls[:n_traced]]
+        ctx = dict(reduction=red, spans=sred, counters=counters, cell=cell,
+                   ticks=ticks, lanes=lanes, device_kind=device.device_kind)
         metrics = {}
         for m in cell.per_layer:
             value = spec.load_reader(m["name"])(ctx)
@@ -197,7 +249,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         log(f"trace: calls={n_traced} window_s={red.window_s!r} "
             f"busy_s={red.busy_s!r} "
             f"device_events={red.n_device_events} "
-            f"kernel_events={red.n_kernel_events}")
+            f"kernel_events={red.n_kernel_events} "
+            f"named_kernel_events={sred and sred.n_kernel_events} "
+            f"kernel_invocations="
+            f"{sum(c['kernel_invocations'] for c in counters)} "
+            f"op_names={len(names)}")
+        log(f"trace_seconds: stop={stop_s!r} lower_compile="
+            f"{t_read[1] - t_read[0]!r} load={t_read[2] - t_read[1]!r} "
+            f"reduce={t_read[3] - t_read[2]!r}")
     else:
         metrics = {"sim_s_per_s": {
             "value": n_calls * lanes * cfg["sim_seconds"] / wall,
@@ -207,6 +266,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     # The check: one of the window's calls, drawn from the seed, against the
     # reference, once the window has closed and the rest is freed.  Its
     # reference run takes about as long as the call itself.
+    t_check = time.perf_counter()
     rng = np.random.default_rng(np.random.SeedSequence([seed % 2**64, 2**32]))
     picked = [int(rng.integers(n_calls))]
     sample = [(calls[i][0], check.program_state(calls[i][1].state, lanes))
@@ -215,17 +275,19 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     gc.collect()
     per_field = {}
     bad_lanes = 0
-    sim = reference.make_simulator(
-        cfg, cell.traffic, spec.make_jobs(cfg),
+    ref_mod = spec.load_reference(cell)
+    sim = ref_mod.make_simulator(
+        cfg, cell.traffic, spec.make_jobs(cfg), float_dtype=jnp.float32,
         tick_end=reference.tick_end_rounding(cfg["dt"]))
     for seeds, prog in sample:
-        ref = reference.run_lanes(sim, cell.traffic, seeds)
+        ref = ref_mod.run_lanes(sim, cell.traffic, seeds)
         mm = check.mismatches(prog, ref)
         for k, v in mm.items():
             per_field[k] = per_field.get(k, 0) + v
         bad_lanes += check.lanes_differing(prog, ref, lanes)
     correct, numbers = check.verdict(per_field)
-    log(f"check: calls={[int(i) for i in picked]} of {n_calls} "
+    log(f"check: seconds={time.perf_counter() - t_check!r} "
+        f"calls={[int(i) for i in picked]} of {n_calls} "
         f"lanes={len(sample) * lanes} mismatched_by_field="
         f"{ {k: v for k, v in per_field.items() if v} }")
     result.update(correct=correct, attempted=n_calls * lanes,

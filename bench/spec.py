@@ -2,11 +2,13 @@
 
 ``BENCHMARK.json`` names each cell's configuration and traffic mix.  A
 configuration is ``bench/configs/<file>.json`` (the deployment: geometry,
-sharing policy, the job population, how long and how wide one experiment
-call is); a traffic mix is ``bench/traffic/<traffic>.json`` (the scheduler
-that serves the population and its parameters); a per-layer metric is read
-by ``bench/metrics/<metric>.py``.  Adding a cell therefore adds files and
-entries and edits none.
+sharing policy, engine options, the job population, how long and how wide
+one experiment call is, and optionally ``"reference"``, the path of the
+plain reference that judges it); a traffic mix is
+``bench/traffic/<traffic>.json`` (the scheduler that serves the population
+and its parameters); a per-layer metric is read by
+``bench/metrics/<metric>.py``.  Adding a cell, or a deployment with a
+mechanism of its own, therefore adds files and entries and edits none.
 """
 from __future__ import annotations
 
@@ -22,6 +24,8 @@ ROOT = BENCH_DIR.parent
 BENCHMARK_FILE = ROOT / "BENCHMARK.json"
 TRAFFIC_DIR = BENCH_DIR / "traffic"
 METRICS_DIR = BENCH_DIR / "metrics"
+#: The reference of a configuration that names none.
+DEFAULT_REFERENCE = BENCH_DIR / "reference.py"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -34,6 +38,7 @@ class Cell:
     config: dict
     traffic_name: str
     traffic: dict
+    reference: Path         # the plain reference that judges the cell
     end_to_end: tuple       # metric entries of BENCHMARK.json
     per_layer: tuple        # metric entries that apply to this cell
 
@@ -58,6 +63,21 @@ def metric_path(name: str) -> Path:
     return METRICS_DIR / f"{name}.py"
 
 
+def reference_path(config: dict) -> Path:
+    """The file of the configuration's plain reference: its
+    ``"reference"`` (a path from the repository root that lies under
+    ``bench/``), else :data:`DEFAULT_REFERENCE`."""
+    if "reference" not in config:
+        return DEFAULT_REFERENCE
+    path = (ROOT / config["reference"]).resolve()
+    if not path.is_relative_to(BENCH_DIR) or path.suffix != ".py":
+        raise ValueError(f"reference {config['reference']!r} is not a .py "
+                         f"file under {BENCH_DIR.name}/")
+    if not path.is_file():
+        raise FileNotFoundError(f"reference: no file {path}")
+    return path
+
+
 def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
@@ -76,18 +96,30 @@ def resolve_cell(name: str, bench: dict | None = None) -> Cell:
     return Cell(
         name=name, chips=int(w["chips"]), config_name=w["config"],
         config=config, traffic_name=w["traffic"], traffic=traffic,
+        reference=reference_path(config),
         end_to_end=tuple(m for m in bench["end_to_end"] if _applies(m, name)),
         per_layer=tuple(m for m in bench["per_layer"] if _applies(m, name)))
 
 
-def load_reader(metric: str):
-    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
-    path = metric_path(metric)
-    spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{metric.replace('.', '_')}", path)
+def _load_module(name: str, path: Path):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str):
+    """The ``read(ctx)`` function of ``bench/metrics/<metric>.py``."""
+    return _load_module(f"bench.metrics.{metric.replace('.', '_')}",
+                        metric_path(metric)).read
+
+
+def load_reference(cell: Cell):
+    """The module of the cell's plain reference, loaded from its file: it
+    has ``make_simulator`` and ``run_lanes`` (the contract is in
+    :mod:`bench.reference`'s docstring)."""
+    rel = cell.reference.relative_to(ROOT).with_suffix("")
+    return _load_module("_".join(rel.parts), cell.reference)
 
 
 # -- the job population ------------------------------------------------------

@@ -1,6 +1,7 @@
 """CPU checks of the benchmark's definition and yardsticks (no chip)."""
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import re
@@ -129,3 +130,70 @@ def test_benchmark_file_is_small_and_one_line_fields(bench):
     for m in bench["per_layer"]:
         assert 1 <= len(m["layer"]) <= 200
     json.dumps(bench)
+
+
+def test_every_configuration_names_a_reference_under_bench(bench):
+    for w in bench["workloads"]:
+        cell = spec.resolve_cell(w["name"], bench)
+        assert cell.reference.is_relative_to(spec.BENCH_DIR)
+        assert cell.reference.is_file()
+        ref = spec.load_reference(cell)
+        assert callable(ref.make_simulator) and callable(ref.run_lanes)
+    # The two published configurations name none: bench/reference.py.
+    for name in ("fig12.themis", "fleet128.tbf"):
+        assert spec.resolve_cell(name, bench).reference == \
+            spec.DEFAULT_REFERENCE
+
+
+#: The engine options each configuration gave ``Experiment`` when the
+#: harness named them one by one; the options from the file are the same.
+NAMED_OPTIONS = ("policy", "n_servers", "n_workers", "server_bw", "max_jobs",
+                 "dt", "wheel", "ring_cap", "bin_ticks", "sync_ticks",
+                 "sinkhorn_iters", "fabric_exponent")
+
+
+@pytest.mark.parametrize("name", ["fig12.themis", "fleet128.themis",
+                                  "fig12.tbf", "fleet128.tbf"])
+def test_engine_options_of_the_existing_cells_are_unchanged(bench, name):
+    from bench.run import build_experiment, engine_options
+    from repro.api import Experiment
+    from repro.core.scheduler import get_scheduler
+
+    cell = spec.resolve_cell(name, bench)
+    c, tr = cell.config, cell.traffic
+    assert engine_options(c) == {k: c[k] for k in NAMED_OPTIONS}
+    params = get_scheduler(tr["scheduler"]).params_cls(**tr.get("params", {}))
+    named = Experiment(scheduler=tr["scheduler"], params=params,
+                       **{k: c[k] for k in NAMED_OPTIONS})
+    exp = build_experiment(cell)
+    assert exp.engine_kw == named.engine_kw
+    assert exp.engine_config() == named.engine_config()
+    assert exp.jobs == named.add_jobs(spec.make_jobs(c)).jobs
+
+
+def test_engine_options_reach_the_experiment_and_harness_keys_do_not(bench):
+    from bench.run import build_experiment
+
+    cell = spec.resolve_cell("fleet128.themis", bench)
+    config = dict(cell.config, shard_servers=1, mesh_shape=[1, 1],
+                  reference="bench/reference.py")
+    exp = build_experiment(dataclasses.replace(cell, config=config))
+    assert exp.engine_kw["shard_servers"] == 1
+    assert exp.engine_kw["mesh_shape"] == (1, 1)
+    assert exp.engine_config().mesh_shape == (1, 1)
+    harness = {"entry", "lanes", "sim_seconds", "jobs", "reference",
+               "source", "deployment", "guarantees", "assumed", "reduced",
+               "precision"}
+    assert not harness & set(exp.engine_kw)
+
+
+@pytest.mark.parametrize("key,value", [("tick_impl", "ref"), ("seed", 3),
+                                       ("scheduler", "fifo")])
+def test_a_configuration_may_not_set_what_the_harness_owns(bench, key,
+                                                          value):
+    from bench.run import engine_options
+
+    config = dict(spec.resolve_cell("fig12.themis", bench).config,
+                  **{key: value})
+    with pytest.raises(ValueError, match=key):
+        engine_options(config)

@@ -176,11 +176,11 @@ def reduce_events(events: dict) -> Reduction:
         idle_gaps=by_time(gaps))
 
 
-def reduce_dir(trace_dir: str) -> Reduction:
-    """Reduce the one ``.xplane.pb`` the profiler wrote under ``trace_dir``."""
+def trace_file(trace_dir: str) -> str:
+    """The one ``.xplane.pb`` the profiler wrote under ``trace_dir``."""
     paths = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     if len(paths) != 1:
         raise FileNotFoundError(f"expected one trace under {trace_dir}, "
                                 f"found {paths}")
-    return reduce_events(load(paths[0]))
+    return paths[0]
