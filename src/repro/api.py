@@ -170,7 +170,10 @@ class RunResult:
         ran, and the counters of the engine call (``ticks`` simulated,
         ``lanes`` advanced together, ``kernel_invocations`` of the fused
         tick step and the ``kernel_grid_steps`` of each (0 on the scan
-        path), ``jit_traces`` of the engine scan, and the persistent
+        path), ``policy_levels`` of the share table's policy chain and the
+        ``chain_elements`` of its transition matrices built per simulated
+        tick of one lane over all servers (both 0 for schedulers without a
+        share table), ``jit_traces`` of the engine scan, and the persistent
         compile cache's ``compile_cache_requests``/``compile_cache_hits``)."""
         return {
             "scheduler": self.scheduler,

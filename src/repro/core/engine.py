@@ -59,7 +59,7 @@ from . import baselines
 from .global_sync import sync_segments
 from .job_table import JobTable, make_table
 from .params import SchedulerParams, stack_params
-from .policy import Policy
+from .policy import Policy, chain_elements
 from .scheduler import Scheduler, TickView, get_scheduler
 from .shard import (AXIS_SERVERS, AXIS_SWEEP, ShardSpec, resolve_shard,
                     state_specs)
@@ -781,16 +781,22 @@ def _kernel_grid_steps(cfg: EngineConfig, sched: Scheduler, tick_impl: str,
                           cfg.n_workers, sched.kernel_select_mode)[1]
 
 
-def _call_counters(marks: tuple, ticks: int, lanes: int, tick_impl: str,
-                   grid_steps: int) -> dict:
+def _call_counters(marks: tuple, cfg: EngineConfig, sched: Scheduler,
+                   ticks: int, lanes: int, tick_impl: str) -> dict:
     """One engine call's counters (see ``RunResult.counters``)."""
     traces, requests, hits = marks
     now_requests, now_hits = cache_events()
+    # The share table's policy chain, built per server every tick.
+    chain = sched.uses_segments
     return {
         "lanes": lanes,
         # One fused invocation per tick serves every lane (vmap folds them).
         "kernel_invocations": ticks if tick_impl == "pallas" else 0,
-        "kernel_grid_steps": grid_steps,
+        "kernel_grid_steps": _kernel_grid_steps(cfg, sched, tick_impl, lanes),
+        "policy_levels": cfg.policy.depth if chain else 0,
+        "chain_elements": (cfg.n_servers * chain_elements(cfg.policy,
+                                                          cfg.max_jobs)
+                           if chain else 0),
         "jit_traces": len(TRACE_LOG) - traces,
         "compile_cache_requests": now_requests - requests,
         "compile_cache_hits": now_hits - hits,
@@ -883,9 +889,7 @@ def run(cfg: EngineConfig, wl: Workload, table: JobTable, sim_seconds: float):
             "ticks": ticks,
             "tick_impl": tick_impl,
         }
-    out["counters"] = _call_counters(
-        marks, ticks, 1, tick_impl,
-        _kernel_grid_steps(cfg, sched, tick_impl, 1))
+    out["counters"] = _call_counters(marks, cfg, sched, ticks, 1, tick_impl)
     return out
 
 
@@ -944,9 +948,8 @@ def run_batch(cfg: EngineConfig, wl: Workload, table: JobTable,
             "tick_impl": tick_impl,
         }
     lanes = len(seeds) * (1 if points is None else len(points))
-    out["counters"] = _call_counters(
-        marks, ticks, lanes, tick_impl,
-        _kernel_grid_steps(cfg, sched, tick_impl, lanes))
+    out["counters"] = _call_counters(marks, cfg, sched, ticks, lanes,
+                                     tick_impl)
     return out
 
 

@@ -29,6 +29,8 @@ vmapped over servers, and run inside the discrete-event engine's `lax.scan`.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -164,9 +166,12 @@ def transition_matrices(
 ) -> list[jnp.ndarray]:
     """Build the chain of transition matrices ``T^0 .. T^{N-1}`` (paper Fig. 4).
 
-    All entity levels are padded to ``J`` slots, so ``T^0`` has shape ``(1, J)``
-    and every subsequent matrix is ``(J, J)``. Rows sum to one (or are all-zero
-    for parents with no live descendants).
+    Entities are indexed over ``J`` slots.  The job level is ``J`` wide; a
+    group or user level has composite ids (``parent·J + raw``), so it is
+    ``J`` times as wide as its parent: ``T^0`` is ``(1, J)``, and under
+    ``group-then-user-fair`` ``T^1`` is ``(J, J²)`` and ``T^2`` is
+    ``(J², J)`` (:func:`chain_elements` counts them).  Rows sum to one (or
+    are all-zero for parents with no live descendants).
     """
     n = active.shape[0]
     mask = active.astype(bool)
@@ -229,7 +234,11 @@ def compute_job_shares(
     """Evaluate Eq. 1: the chain product of the transition matrices.
 
     Returns ``f32[J]`` job shares that sum to 1 over live (and, if ``demand``
-    is given, demanded) jobs — or all zeros when nothing is live.
+    is given, demanded) jobs — or all zeros when nothing is live.  Each
+    product (over mid levels ``J²`` wide; see :func:`transition_matrices`)
+    is taken at ``Precision.HIGHEST``: at the TPU's default precision an
+    f32 matmul may round its operands to bfloat16, which would move
+    non-dyadic shares such as 1/3.
     """
     mats = transition_matrices(
         policy, active=active, user_id=user_id, group_id=group_id,
@@ -237,8 +246,23 @@ def compute_job_shares(
     )
     vec = jnp.ones((1, 1), dtype=jnp.float32)
     for tm in mats:
-        vec = vec @ tm
+        vec = jnp.matmul(vec, tm, precision=jax.lax.Precision.HIGHEST)
     return vec[0]
+
+
+@functools.lru_cache(maxsize=None)
+def chain_elements(policy: Policy, n: int) -> int:
+    """Elements of the transition matrices :func:`transition_matrices`
+    builds over ``n`` slots, ``sum_l rows_l * cols_l``, read off the shapes
+    it returns (traced abstractly once per policy and ``n``; nothing is
+    computed)."""
+    slot = jax.ShapeDtypeStruct((n,), jnp.int32)
+    mats = jax.eval_shape(
+        lambda a, u, g, s, p: transition_matrices(
+            policy, active=a, user_id=u, group_id=g, size=s, priority=p),
+        jax.ShapeDtypeStruct((n,), jnp.bool_), slot, slot, slot,
+        jax.ShapeDtypeStruct((n,), jnp.float32))
+    return sum(math.prod(m.shape) for m in mats)
 
 
 def compute_job_shares_from_table(policy: Policy, table, demand=None) -> jnp.ndarray:

@@ -146,9 +146,12 @@ class TestRunResult:
         assert set(c) == {"scheduler", "policy", "params_hash", "dropped",
                           "idle_worker_ticks", "tick_impl", "ticks", "lanes",
                           "kernel_invocations", "kernel_grid_steps",
+                          "policy_levels", "chain_elements",
                           "jit_traces",
                           "compile_cache_requests", "compile_cache_hits"}
         assert c["tick_impl"] in ("ref", "pallas")
+        # job-fair over the facade's 8 slots on one server: T^0 is [1, 8].
+        assert (c["policy_levels"], c["chain_elements"]) == (1, 8)
         json.dumps(c)
 
 

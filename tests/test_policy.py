@@ -1,11 +1,16 @@
 """Policy chain (paper §3, Eq. 1): worked examples + hypothesis invariants."""
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from _hypothesis_shim import given, settings, st
 
-from repro.core.policy import Level, Policy, compute_job_shares_from_table, transition_matrices
+from repro.core.policy import (Level, Policy, chain_elements,
+                               compute_job_shares_from_table,
+                               transition_matrices)
 from repro.core.job_table import make_table
 
 J = 16
@@ -123,6 +128,72 @@ class TestPolicyParsing:
         assert [l.entity for l in p.levels] == ["user", "job"]
         p = Policy.parse("group:size")
         assert p.levels[0].weight == "size"
+
+
+# The multi-tenant deployment's 64 slots: 3 groups and 12 users.  Group 0:
+# user 0 has 24 jobs, user 1 has 8; group 1: users 2-5 have 8, 6, 4, 2;
+# group 2: users 6-11 have 2 each.
+TENANT_USERS = ([0] * 24 + [1] * 8 + [2] * 8 + [3] * 6 + [4] * 4 + [5] * 2
+                + [u for u in range(6, 12) for _ in range(2)])
+TENANT_GROUPS = [0] * 32 + [1] * 20 + [2] * 12
+
+
+class TestChainPrecision:
+    """Eq. 1 at full precision: the product is the one the benchmark's plain
+    reference takes (``bench.reference.chain_shares``)."""
+
+    def test_every_product_is_taken_at_highest_precision(self):
+        t = make_table([{"group": g, "user": u} for g, u in
+                        zip(TENANT_GROUPS, TENANT_USERS)], max_jobs=64)
+        text = jax.jit(lambda d: compute_job_shares_from_table(
+            Policy.parse("group-then-user-fair"), t, d)).lower(
+                jnp.ones((64,), bool)).as_text()
+        dots = [line for line in text.splitlines() if "dot_general" in line]
+        assert len(dots) == 3
+        assert all("precision = [HIGHEST, HIGHEST]" in d for d in dots)
+
+    @pytest.mark.parametrize("live", ["all", "every_other", "one_group_idle"])
+    def test_tenant_shares_equal_the_reference_bit_for_bit(self, live):
+        from bench.reference import chain_shares
+
+        mask = {"all": np.ones(64, bool),
+                "every_other": np.arange(64) % 2 == 0,
+                "one_group_idle": np.array(TENANT_GROUPS) != 1}[live]
+        jobs = [{"group": g, "user": u}
+                for g, u in zip(TENANT_GROUPS, TENANT_USERS)]
+        got = np.asarray(jax.jit(lambda d: compute_job_shares_from_table(
+            Policy.parse("group-then-user-fair"), make_table(jobs, 64), d))(
+                jnp.asarray(mask)))
+        want = np.asarray(jax.jit(functools.partial(
+            chain_shares, ("group", "user", "job"),
+            user=jnp.asarray(TENANT_USERS), group=jnp.asarray(TENANT_GROUPS),
+            fdt=jnp.float32))(jnp.asarray(mask)))
+        np.testing.assert_array_equal(got, want)
+        assert got.sum() == pytest.approx(1.0, abs=1e-6)
+        if live == "all":
+            # 1/3 per group; user 0's 24 jobs 1/144 ... users 6-11's 1/36,
+            # user 5's two jobs 1/24.
+            expect = np.float32([1 / 144] * 24 + [1 / 48] * 8 + [1 / 96] * 8
+                                + [1 / 72] * 6 + [1 / 48] * 4 + [1 / 24] * 2
+                                + [1 / 36] * 12)
+            np.testing.assert_allclose(got, expect, rtol=2e-7)
+
+
+@pytest.mark.parametrize("name,n,elements", [
+    ("job-fair", 8, 8),
+    ("user-then-job-fair", 8, 8 + 8 * 8),
+    # Composite ids: [1, 64] + [64, 64²] + [64², 64].
+    ("group-then-user-fair", 64, 64 + 2 * 64 ** 3)])
+def test_chain_elements_are_the_built_matrices_elements(name, n, elements):
+    """The counter is read off what ``transition_matrices`` builds, so a
+    change to the matrices' shapes moves it with no second formula."""
+    policy = Policy.parse(name)
+    t = make_table([{"group": i % 3, "user": i % 5} for i in range(n)],
+                   max_jobs=n)
+    mats = transition_matrices(policy, active=t.active, user_id=t.user_id,
+                               group_id=t.group_id, size=t.size,
+                               priority=t.priority)
+    assert sum(m.size for m in mats) == chain_elements(policy, n) == elements
 
 
 @st.composite

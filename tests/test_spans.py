@@ -5,8 +5,9 @@ spans of :data:`repro.core.spans.HOST_SPANS`, once each, in order, inside
 the call.  Device scopes: every phase of the tick names its operations
 (``tick/<phase>``), on the single-device and the sharded tick, and the
 fused kernel keeps its name.  Counters: ``RunResult.counters()`` counts the
-call's ticks, lanes, kernel invocations and jit traces.  None of it
-changes a result (the bit-identity tests of the engine still hold).
+call's ticks, lanes, kernel invocations, policy-chain depth and elements,
+and jit traces.  None of it changes a result (the bit-identity tests of
+the engine still hold).
 """
 import glob
 import os
@@ -124,6 +125,8 @@ def test_counters_count_the_call(impl):
         assert c["kernel_invocations"] == (20 if impl == "pallas" else 0)
         # 2 servers x up to 3 lanes fold into one block of one grid step.
         assert c["kernel_grid_steps"] == (1 if impl == "pallas" else 0)
+        # job-fair over 8 slots on each of 2 servers: T^0 is [1, 8].
+        assert (c["policy_levels"], c["chain_elements"]) == (1, 16)
         assert c["jit_traces"] == 1
         assert 0 <= c["compile_cache_hits"] <= c["compile_cache_requests"]
     # A lane sliced out of a batch keeps the counters of its call.

@@ -5,9 +5,11 @@ XLA on the CPU, so it cannot see what the TPU compiler refuses: primitives
 Mosaic has no lowering for, unaligned slices, VMEM overflow.  These tests
 compile ``tick_step_pallas`` (both modes) and ``token_select_pallas`` with
 ``interpret=False`` for one chip of a described ``v5e:2x2`` topology, at
-paper geometry (S=8, J=16, W=8), fleet geometry (S=128, J=1024, W=4) and
-the 128-node cell's (S=128, J=8, W=8: themis in one grid step).
-Nothing runs; a compile that passes is not a chip run.
+paper geometry (S=8, J=16, W=8), fleet geometry (S=128, J=1024, W=4), the
+128-node cell's (S=128, J=8, W=8: themis in one grid step) and the
+multi-tenant cell's (S=128, J=64, W=8: themis mode, and the per-server
+``group-then-user-fair`` policy chain with no operand rounded to
+bfloat16).  Nothing runs; a compile that passes is not a chip run.
 
 The topology is described inside a module fixture — never at import, in
 ``conftest.py`` or in ``parametrize`` — so every pytest worker collects the
@@ -21,12 +23,17 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.global_sync import local_segments
+from repro.core.job_table import JobTable
+from repro.core.policy import Policy
 from repro.kernels.tick_step.kernel import tick_step_pallas
 from repro.kernels.tick_step.ref import MODES
 from repro.kernels.token_select.kernel import token_select_pallas
 
 GEOMETRIES = {"paper": (8, 16, 8), "fleet": (128, 1024, 4),
               "fleet128": (128, 8, 8)}
+#: The multi-tenant cell: 64 job slots on 128 nodes, themis only.
+TENANTS128 = (128, 64, 8)
 
 
 @pytest.fixture(scope="module")
@@ -83,3 +90,36 @@ def test_token_select_compiles_for_v5e(one_chip, geometry):
     text = _compiled_text(
         functools.partial(token_select_pallas, interpret=False), args)
     assert "tpu_custom_call" in text
+
+
+def test_tenant_cell_kernels_compile_for_v5e(one_chip):
+    s, j, w = TENANTS128
+    text = _compiled_text(
+        functools.partial(tick_step_pallas, mode="themis", interpret=False),
+        (_spec((s, j), jnp.float32, one_chip),
+         _spec((s, j), jnp.int32, one_chip), None,
+         _spec((s, w), jnp.bool_, one_chip),
+         _spec((s, w), jnp.float32, one_chip)))
+    assert "tpu_custom_call" in text
+
+
+def test_group_then_user_fair_chain_compiles_for_v5e(one_chip):
+    """The per-server chain of the multi-tenant cell: three levels over 64
+    slots, ``[1, 64]``, ``[64, 64²]`` and ``[64², 64]`` on each of 128
+    servers.  The product is taken at full precision, so the chip's
+    compiler rounds none of its operands to bfloat16."""
+    s, j, _ = TENANTS128
+    policy = Policy.parse("group-then-user-fair")
+
+    def chain(active, user, group, demand):
+        ones = jnp.ones((j,), jnp.float32)
+        table = JobTable(active=active, user_id=user, group_id=group,
+                         size=ones, priority=ones, last_heartbeat=ones)
+        return local_segments(policy, table, demand)
+
+    compiled = jax.jit(chain).lower(
+        _spec((j,), jnp.bool_, one_chip), _spec((j,), jnp.int32, one_chip),
+        _spec((j,), jnp.int32, one_chip),
+        _spec((s, j), jnp.bool_, one_chip)).compile()
+    assert "bf16" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
